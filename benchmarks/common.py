@@ -29,6 +29,7 @@ from repro.data import (
     two_class_partition,
 )
 from repro.fl import ClientConfig, FLServer, ServerConfig, make_strategy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.recurrent import (
     LSTMConfig,
     MLPConfig,
@@ -44,6 +45,10 @@ from repro.nn.vision import VGG_SMALL_PLAN, VGGConfig, init_vgg, vgg_accuracy, v
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "artifacts")
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# on from the first import (``benchmarks.run`` imports this module
+# before its first compile)
+enable_compile_cache()
 
 
 def write_artifact(name: str, art: dict) -> str:

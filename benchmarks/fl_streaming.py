@@ -315,11 +315,20 @@ def fleet_section(sizes=(10_000, 100_000, 1_000_000), rounds: int = 2,
                   participation: float = 0.01) -> dict:
     """Acceptance: host RSS stays flat (within 1.5x) from 10k to 1M
     clients at 1% participation. Each size runs in a fresh subprocess
-    so ``ru_maxrss`` measures that fleet alone."""
+    so ``ru_maxrss`` measures that fleet alone.
+
+    The children use the device, and a device belongs to one process:
+    a parent that has started JAX would hold it, and the children would
+    fail or hang. So this runs only in a process that has not imported
+    JAX yet."""
     import os
     import subprocess
     import sys
 
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "fleet_section starts one device-using child per fleet size; "
+            "call it before this process imports JAX")
     rows = []
     for n in sizes:
         proc = subprocess.run(
@@ -352,6 +361,9 @@ def fleet_smoke(clients: int = 10_000, rounds: int = 2,
 
 def run_bench(clients: int = 256, chunk: int = 16, rounds: int = 3,
               fleet_sizes=(10_000, 100_000, 1_000_000)) -> dict:
+    """The whole artifact. A non-empty ``fleet_sizes`` runs the fleet
+    children first, while this process has not touched JAX."""
+    fleet = fleet_section(fleet_sizes) if fleet_sizes else None
     rows = [
         engine_row("sequential", min(clients, 64), chunk, rounds=1),
         engine_row("batched", clients, chunk, rounds=rounds),
@@ -366,8 +378,9 @@ def run_bench(clients: int = 256, chunk: int = 16, rounds: int = 3,
         "engines": rows,
         "scale_1024": scale_1024(chunk),
         "kernel": kernel_rows(),
-        "fleet": fleet_section(fleet_sizes),
     }
+    if fleet is not None:
+        art["fleet"] = fleet
     if "peak_bytes" in bat and "peak_bytes" in stream:
         art["peak_reduction_at_%d" % clients] = (
             bat["peak_bytes"] / stream["peak_bytes"])
@@ -380,8 +393,11 @@ def run_bench(clients: int = 256, chunk: int = 16, rounds: int = 3,
 
 
 def csv_rows(clients: int = 256, chunk: int = 16):
-    """Rows for benchmarks.run CSV: (name, us_per_call, derived)."""
-    art = run_bench(clients, chunk)
+    """Rows for benchmarks.run CSV: (name, us_per_call, derived).
+    ``benchmarks.run`` has touched JAX before it gets here, so the
+    fleet sweep (device-using children) is left out; its in-process
+    slice is the ``fl_fleet_smoke`` group."""
+    art = run_bench(clients, chunk, fleet_sizes=())
     rows = []
     for r in art["engines"]:
         name = f"fl_{r['engine']}_{r['clients']}c"
@@ -394,11 +410,6 @@ def csv_rows(clients: int = 256, chunk: int = 16):
     s = art["scale_1024"]
     rows.append(("fl_streaming_1024c", s["streaming_round_s"] * 1e6,
                  f"batched_peak_est_x={s.get('batched_over_streaming_peak', 0):.1f}"))
-    f = art["fleet"]
-    biggest = f["rows"][-1]
-    rows.append((f"fl_fleet_{biggest['clients']}c",
-                 biggest["round_s"] * 1e6,
-                 f"rss_max_over_min={f['rss_max_over_min']:.2f}x"))
     return rows
 
 

@@ -70,7 +70,7 @@ _LAX_HEADS = (
     "jax.lax.switch", "lax.switch", "jax.lax.map", "lax.map",
     "jax.lax.associative_scan", "lax.associative_scan",
 )
-_SHARD_HEADS = ("shard_map", "jax.experimental.shard_map.shard_map")
+_SHARD_HEADS = ("shard_map", "jax.shard_map")
 _PALLAS_HEADS = ("pl.pallas_call", "pallas_call",
                  "jax.experimental.pallas.pallas_call")
 _CALLBACK_HEADS = ("jax.pure_callback", "pure_callback",

@@ -43,6 +43,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # --------------------------------------------------------------- counter
 
@@ -93,9 +94,9 @@ class CompileCounter:
 def _eqn_subjaxprs(eqn):
     for v in eqn.params.values():
         for x in (v if isinstance(v, (tuple, list)) else (v,)):
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 yield x
 
 

@@ -21,25 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6: top-level export, `check_vma` kwarg
-    from jax import shard_map as _shard_map
-    _VMA_KW = "check_vma"
-except ImportError:  # jax 0.4.x/0.5.x: experimental module, `check_rep` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _VMA_KW = "check_rep"
-
-
-def shard_map(f=None, **kw):
-    """Version-compatible ``shard_map`` accepting either ``check_vma`` or
-    ``check_rep`` and mapping to whatever this jax spells it."""
-    flag = kw.pop("check_vma", kw.pop("check_rep", None))
-    if flag is not None:
-        kw[_VMA_KW] = flag
-    if f is None:
-        return functools.partial(_shard_map, **kw)
-    return _shard_map(f, **kw)
-
-
 # ----------------------------------------------------------- bucketed psum
 
 def plan_buckets(tree: Any, bucket_bytes: int = 4 << 20) -> List[List[int]]:
@@ -65,7 +46,7 @@ def bucketed_pmean(tree: Any, mesh: Mesh, axis: str = "pod",
     buckets = plan_buckets(tree, bucket_bytes)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(), out_specs=P(),
         check_vma=False,
     )
@@ -120,8 +101,8 @@ def quantized_pmean(tree: Any, mesh: Mesh, axis: str = "pod") -> Any:
     """bf16-quantized cross-pod mean (2x DCN traffic cut; FedPAQ-style
     uplink quantization applied to the pod sync)."""
     def one(x):
-        @functools.partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
-                           check_vma=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(),
+                           out_specs=P(), check_vma=False)
         def red(v):
             return jax.lax.pmean(v, axis)
 

@@ -169,14 +169,19 @@ class ClientArena:
     # ------------------------------------------------------------ sharding
     def shard_rows(self, mesh, axis: str = "clients") -> None:
         """Shard every arena leaf's row axis over ``mesh[axis]`` (no-op
-        unless the row count divides evenly — the scratch row makes
-        ``clients + 1`` rows, so pick fleets accordingly when sharding)."""
+        without a mesh). The scratch row makes ``clients + 1`` rows, and
+        they must divide evenly over the axis: otherwise this raises
+        rather than leave the whole fleet on one device."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if mesh is None or axis not in mesh.axis_names:
             return
-        if (self.n_clients + 1) % mesh.shape[axis]:
-            return
+        ndev = mesh.shape[axis]
+        if (self.n_clients + 1) % ndev:
+            raise ValueError(
+                f"arena rows (clients + 1 = {self.n_clients + 1}) do not "
+                f"divide over the {ndev} devices of mesh axis {axis!r}; "
+                f"choose clients so that clients + 1 is a multiple of {ndev}")
         sharding = NamedSharding(mesh, P(axis))
 
         def put(tree):

@@ -107,17 +107,15 @@ def batched_local_update(
 
     f = jax.vmap(one_client)
     if mesh is not None and axis in mesh.axis_names:
-        from repro.distributed.collectives import shard_map
-
         C = step_mask.shape[0]
         ndev = mesh.shape[axis]
         pad = -C % ndev
         spec = P(axis)
-        f_sharded = shard_map(
+        f_sharded = jax.shard_map(
             jax.vmap(one_client), mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=(spec, spec, spec, spec),
-            check_rep=False)
+            check_vma=False)
         if pad == 0:
             f = f_sharded
         else:

@@ -264,6 +264,14 @@ class FLServer:
         mesh_axis: str = "clients",
     ):
         self.loss_fn = loss_fn
+        if mesh is not None:
+            # replicated over the mesh from the start, as every round's
+            # output is: the round programs then see one set of input
+            # shardings and compile once
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            global_params = jax.device_put(
+                global_params, NamedSharding(mesh, PartitionSpec()))
         self.global_params = global_params
         self.data = data
         self.partitions = partitions

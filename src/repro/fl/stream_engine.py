@@ -75,7 +75,7 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.parameterization import apply_rank_mask
 from repro.fl import faults as faults_lib
@@ -153,6 +153,17 @@ class StreamingRound:
         jitted program is traced once per shape signature)."""
         return self._data_source.fetch(int(np.asarray(chunk_idx)))
 
+    def chunk_sharding(self, chunk: int) -> Optional[NamedSharding]:
+        """How the chunk-stacked ``(n_chunks, chunk, ...)`` inputs are
+        placed: each chunk's clients split over the mesh axis, or
+        ``None`` without a mesh or when ``chunk`` does not divide over
+        it (the program then folds on one device, single-level)."""
+        mesh, axis = self.mesh, self.mesh_axis
+        if (mesh is None or axis not in mesh.axis_names
+                or chunk % mesh.shape[axis]):
+            return None
+        return NamedSharding(mesh, P(None, axis))
+
     # ------------------------------------------------------- the program
     def _round_program(self, state_xs, resident_xs, batches_xs, step_mask_xs,
                        mask_xs, sizes_xs, quant_keys_xs, lr, server_state,
@@ -162,8 +173,7 @@ class StreamingRound:
         mode = self.personalization
         mesh, axis = self.mesh, self.mesh_axis
         chunk = step_mask_xs.shape[1]   # actual width (≤ configured)
-        two_level = (mesh is not None and axis in mesh.axis_names
-                     and chunk % mesh.shape[axis] == 0)
+        two_level = self.chunk_sharding(chunk) is not None
         hetero = tier_payload_masks is not None
         n_tiers = (jax.tree.leaves(tier_payload_masks)[0].shape[0]
                    if hetero else 1)
@@ -363,17 +373,27 @@ class StreamingRound:
                     "pass batches_xs=None when a data_source is given")
             self._data_source = data_source
             self._data_shapes = data_source.chunk_struct()
+        sharding = self.chunk_sharding(np.shape(step_mask_xs)[1])
+
+        def place(tree, dtype=None):
+            # on a mesh the chunk's client axis lands split over it, as
+            # the program's shard_map reads it
+            def one(x):
+                x = x if dtype is None else x.astype(dtype)
+                return (jnp.asarray(x) if sharding is None
+                        else jax.device_put(x, sharding))
+
+            return None if tree is None else jax.tree.map(one, tree)
+
         return self._program(
-            state_xs, resident_xs,
-            None if batches_xs is None
-            else jax.tree.map(jnp.asarray, batches_xs),
-            jnp.asarray(step_mask_xs, jnp.float32),
-            jnp.asarray(mask_xs, jnp.float32),
-            jnp.asarray(sizes_xs, jnp.float32),
-            quant_keys_xs, jnp.asarray(lr, jnp.float32),
+            place(state_xs), place(resident_xs), place(batches_xs),
+            place(step_mask_xs, jnp.float32),
+            place(mask_xs, jnp.float32),
+            place(sizes_xs, jnp.float32),
+            place(quant_keys_xs), jnp.asarray(lr, jnp.float32),
             server_state, agg_target, down_payload,
-            None if tier_xs is None else jnp.asarray(tier_xs, jnp.int32),
-            tier_payload_masks, tier_full_masks, fault_xs, stale_ref)
+            place(tier_xs, jnp.int32),
+            tier_payload_masks, tier_full_masks, place(fault_xs), stale_ref)
 
 
 def chunk_layout(n_clients: int, chunk: int) -> Tuple[int, int, int]:

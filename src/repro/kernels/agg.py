@@ -192,12 +192,10 @@ def sharded_tree_dequant_acc(wire: Any, weights: jax.Array, mesh, axis: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.collectives import shard_map
-
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P(axis)), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def reduce_shard(wire_s, w_s):
         part = tree_dequant_acc(acc_zeros_like(wire_s), wire_s, w_s,
                                 interpret=interpret, use_pallas=use_pallas)

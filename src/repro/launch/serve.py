@@ -42,6 +42,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.data import iid_partition, make_token_lm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.transformer import ModelOptions, build_model
 from repro.serve import ServeEngine
 
@@ -208,6 +209,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: both modes, parity + 0-recompile asserts")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         run_smoke(args)

@@ -35,6 +35,7 @@ from repro.distributed.fedpod import (
     make_dp_step, make_fed_round, stack_for_pods)
 from repro.distributed.sharding import use_rules
 from repro.launch import specs as specs_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn.transformer import ModelOptions, build_model
 from repro.optim import adamw, chain_clip
 
@@ -168,10 +169,16 @@ def train_fl(args):
                          "benchmarks/ for VGG16/LSTM experiments")
 
     parts = dirichlet_partition(tr["y"], args.clients, 0.5, seed=args.seed)
-    mesh = None
-    if (args.engine in ("batched", "streaming", "async")
-            and len(jax.devices()) > 1):
-        mesh = Mesh(np.array(jax.devices()), ("clients",))
+    devices = jax.devices()
+    if args.devices > len(devices):
+        raise SystemExit(f"--devices {args.devices}: only {len(devices)} "
+                         "visible")
+    mesh = None   # one device: every array stays on JAX's default, devices[0]
+    if args.devices > 1:
+        if args.engine == "sequential":
+            raise SystemExit("--devices > 1 needs the batched, streaming "
+                             "or async engine")
+        mesh = Mesh(np.array(devices[: args.devices]), ("clients",))
     gamma_tiers = tuple(float(g) for g in args.gamma_tiers.split(",")
                         if g.strip()) if args.gamma_tiers else ()
     plan = (FaultPlan(rate=args.fault_rate, seed=args.seed)
@@ -267,6 +274,11 @@ def main():
     ap.add_argument("--max-staleness", type=int, default=-1,
                     help="async engine: drop arrivals staler than this "
                          "many versions (-1 = never drop)")
+    ap.add_argument("--devices", type=int, default=1,
+                    help="fl mode: chips the round runs on. 1 (default) "
+                         "uses the first device only; N > 1 shards the "
+                         "client axis over a ('clients',) mesh of the "
+                         "first N devices")
     ap.add_argument("--client-chunk", type=int, default=16,
                     help="streaming engine: clients per scan step; round "
                          "memory peaks at O(client_chunk * model)")
@@ -320,6 +332,7 @@ def main():
                          "Applies to both --mode fl (MLP param cfg) and "
                          "--mode pods (transformer ModelOptions)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "pods":
         train_pods(args)
     else:
