@@ -360,10 +360,13 @@ def fleet_smoke(clients: int = 10_000, rounds: int = 2,
 
 
 def run_bench(clients: int = 256, chunk: int = 16, rounds: int = 3,
-              fleet_sizes=(10_000, 100_000, 1_000_000)) -> dict:
-    """The whole artifact. A non-empty ``fleet_sizes`` runs the fleet
-    children first, while this process has not touched JAX."""
-    fleet = fleet_section(fleet_sizes) if fleet_sizes else None
+              fleet_sizes=(10_000, 100_000, 1_000_000),
+              fleet: dict = None) -> dict:
+    """The whole artifact. ``fleet`` is a :func:`fleet_section` result
+    measured earlier; without one, a non-empty ``fleet_sizes`` runs the
+    fleet children first, while this process has not touched JAX."""
+    if fleet is None and fleet_sizes:
+        fleet = fleet_section(fleet_sizes)
     rows = [
         engine_row("sequential", min(clients, 64), chunk, rounds=1),
         engine_row("batched", clients, chunk, rounds=rounds),
@@ -392,12 +395,12 @@ def run_bench(clients: int = 256, chunk: int = 16, rounds: int = 3,
     return art
 
 
-def csv_rows(clients: int = 256, chunk: int = 16):
+def csv_rows(clients: int = 256, chunk: int = 16, fleet: dict = None):
     """Rows for benchmarks.run CSV: (name, us_per_call, derived).
-    ``benchmarks.run`` has touched JAX before it gets here, so the
-    fleet sweep (device-using children) is left out; its in-process
-    slice is the ``fl_fleet_smoke`` group."""
-    art = run_bench(clients, chunk, fleet_sizes=())
+    ``benchmarks.run`` has touched JAX before it gets here, so it runs
+    the fleet sweep (device-using children) first and passes it in as
+    ``fleet``; without it the ``fl_fleet_<N>c`` row is left out."""
+    art = run_bench(clients, chunk, fleet_sizes=(), fleet=fleet)
     rows = []
     for r in art["engines"]:
         name = f"fl_{r['engine']}_{r['clients']}c"
@@ -410,6 +413,12 @@ def csv_rows(clients: int = 256, chunk: int = 16):
     s = art["scale_1024"]
     rows.append(("fl_streaming_1024c", s["streaming_round_s"] * 1e6,
                  f"batched_peak_est_x={s.get('batched_over_streaming_peak', 0):.1f}"))
+    if "fleet" in art:
+        f = art["fleet"]
+        biggest = f["rows"][-1]
+        rows.append((f"fl_fleet_{biggest['clients']}c",
+                     biggest["round_s"] * 1e6,
+                     f"rss_max_over_min={f['rss_max_over_min']:.2f}x"))
     return rows
 
 

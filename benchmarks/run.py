@@ -1,5 +1,6 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
 import argparse
+import functools
 import importlib
 import sys
 import traceback
@@ -31,10 +32,23 @@ def main() -> None:
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args()
 
-    from benchmarks import roofline, tables
-
     print("name,us_per_call,derived")
     failures = 0
+    # fl_streaming's fleet sweep starts one device-using child per fleet
+    # size; a device belongs to one process, so they run before this
+    # process imports JAX (fl_streaming itself imports none at load)
+    fleet = None
+    if _selected(args.only, dict(MODULES)["fl_streaming"]):
+        from benchmarks import fl_streaming
+
+        try:
+            fleet = fl_streaming.fleet_section()
+        except Exception as e:
+            failures += 1
+            print(f"fl_fleet,0,ERROR:{type(e).__name__}:{e}", flush=True)
+            traceback.print_exc(file=sys.stderr)
+
+    from benchmarks import roofline, tables
 
     def emit(group, rows_fn):
         nonlocal failures
@@ -53,8 +67,10 @@ def main() -> None:
     for modname, terms in MODULES:
         if not _selected(args.only, terms):
             continue
-        emit(modname,
-             importlib.import_module(f"benchmarks.{modname}").csv_rows)
+        rows_fn = importlib.import_module(f"benchmarks.{modname}").csv_rows
+        if modname == "fl_streaming":
+            rows_fn = functools.partial(rows_fn, fleet=fleet)
+        emit(modname, rows_fn)
     if not args.skip_roofline:
         for name, us, derived in roofline.csv_rows():
             print(f"{name},{us:.1f},{derived}", flush=True)

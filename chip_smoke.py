@@ -138,18 +138,14 @@ def make_server(s: Setup, task, *, engine: str, use_pallas: bool,
                 mesh=None) -> FLServer:
     """The LSTM federation through the public API: the streaming engine
     keeps client state in the device arena, the sequential reference in
-    host dicts (the only store it has). The held-out loss is computed
-    on plain XLA ops in every run: it reads the model, not the kernels,
-    and on a mesh the global model is replicated over the devices, which
-    a Mosaic kernel outside a ``shard_map`` cannot be partitioned for."""
-    def model(pallas: bool) -> LSTMConfig:
-        return dataclasses.replace(
-            s.model, param=dataclasses.replace(s.model.param,
-                                               use_pallas=pallas))
-
-    cfg, xla_cfg = model(use_pallas), model(False)
+    host dicts (the only store it has). The held-out loss runs on the
+    same layers as training, Pallas kernels included; on a mesh it reads
+    the global model replicated over the devices."""
+    cfg = dataclasses.replace(
+        s.model, param=dataclasses.replace(s.model.param,
+                                           use_pallas=use_pallas))
     train, test, parts = task
-    eval_loss = jax.jit(lambda p: lstm_loss(p, xla_cfg, test))
+    eval_loss = jax.jit(lambda p: lstm_loss(p, cfg, test))
 
     def loss_fn(p, b):
         return lstm_loss(p, cfg, b)
@@ -288,23 +284,16 @@ def mesh_phase(devices, s: Setup = Setup()) -> dict:
 
     mesh = Mesh(np.array(devices), ("clients",))
     srv = make_server(s, task, engine="streaming", use_pallas=True, mesh=mesh)
-    seen = {}
-    program = srv._stream._program
-
-    def spy(*args):   # the chunk inputs as they reach the round program
-        seen.setdefault("batches", jax.tree.leaves(args[2])[0])
-        seen.setdefault("mask", args[4])
-        return program(*args)
-
-    srv._stream._program = spy
     recs, secs = run_rounds(srv, s.rounds)
+    placed = srv.round_engine.placement   # the last round's chunk inputs
 
     def split_rows(arr, axis: int, spec) -> list:
         sh = arr.sharding
         _require(isinstance(sh, NamedSharding) and sh.spec == spec
                  and len(sh.device_set) == n,
                  f"{spec} not sharded over {n} devices: {sh}")
-        rows = sorted(x.data.shape[axis] for x in arr.addressable_shards)
+        rows = sorted(len(range(*idx[axis].indices(arr.shape[axis])))
+                      for idx in sh.devices_indices_map(arr.shape).values())
         _require(rows == [arr.shape[axis] // n] * n,
                  f"uneven shards {rows} of {arr.shape}")
         return rows
@@ -313,9 +302,9 @@ def mesh_phase(devices, s: Setup = Setup()) -> dict:
            "arena_rows_per_device": split_rows(
                srv.arena.participation, 0, P("clients")),
            "chunk_clients_per_device": split_rows(
-               seen["batches"], 1, P(None, "clients")),
+               placed["batches"], 1, P(None, "clients")),
            "mask_clients_per_device": split_rows(
-               seen["mask"], 1, P(None, "clients")),
+               placed["mask"], 1, P(None, "clients")),
            "mesh": _times(secs),
            "one_device": _times(one_secs)}
     check(out, {"mesh_vs_one_device": MATCHED})

@@ -423,6 +423,13 @@ class FLServer:
                 f"unknown engine {server_cfg.engine!r} "
                 "(expected sequential | batched | streaming | async)")
 
+    @property
+    def round_engine(self) -> Any:
+        """The compiled engine running the rounds: a ``ClientBatch``,
+        ``StreamingRound`` or ``AsyncDispatch``; ``None`` for the
+        sequential reference."""
+        return self._engine or self._stream or self._adispatch
+
     # ------------------------------------------------------------ payload
     def _download_payload(self, cid: int) -> Any:
         p = self.global_params

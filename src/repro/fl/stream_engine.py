@@ -136,6 +136,9 @@ class StreamingRound:
                                 donate_argnums=(0, 1))
         self._data_source = None      # lazy per-chunk batch provider
         self._data_shapes = None      # its pure_callback result struct
+        # the last round's placed chunk inputs, as read back from the
+        # arrays: name -> ShapeDtypeStruct carrying its sharding
+        self.placement = {}
 
     # --------------------------------------------------- param assembly
     def _assemble(self, resident_chunk, down_payload, chunk: int):
@@ -385,10 +388,17 @@ class StreamingRound:
 
             return None if tree is None else jax.tree.map(one, tree)
 
+        batches = place(batches_xs)
+        step_mask = place(step_mask_xs, jnp.float32)
+        mask = place(mask_xs, jnp.float32)
+        placed = {"step_mask": step_mask, "mask": mask}
+        if batches is not None:
+            placed["batches"] = jax.tree.leaves(batches)[0]
+        self.placement = {
+            name: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+            for name, x in placed.items()}
         return self._program(
-            place(state_xs), place(resident_xs), place(batches_xs),
-            place(step_mask_xs, jnp.float32),
-            place(mask_xs, jnp.float32),
+            place(state_xs), place(resident_xs), batches, step_mask, mask,
             place(sizes_xs, jnp.float32),
             place(quant_keys_xs), jnp.asarray(lr, jnp.float32),
             server_state, agg_target, down_payload,

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import blocks
+from repro.kernels import blocks, spmd
 
 _QKEYS = frozenset(("q", "scale"))
 
@@ -106,7 +106,7 @@ def dequant_acc(
     Cp, Lp = qp.shape
     grid = (Lp // bl, Cp // bc)   # client axis innermost => sequential
 
-    out = pl.pallas_call(
+    out = spmd.call(pl.pallas_call(
         functools.partial(_agg_body, n_kc=grid[1]),
         grid=grid,
         in_specs=[
@@ -119,7 +119,7 @@ def dequant_acc(
         scratch_shapes=[pltpu.VMEM((1, bl), jnp.float32)],
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(coeffp, qp, accp)
+    ), coeffp, qp, accp)
     return out[0, :L]
 
 

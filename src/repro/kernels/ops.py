@@ -2,7 +2,8 @@
 
 ``interpret`` defaults to True when no TPU is present so the same code
 path runs (slowly but correctly) on CPU; on TPU backends the compiled
-Mosaic kernels are used.
+Mosaic kernels are used. Operands placed over a multi-device mesh run
+each kernel replicated under ``shard_map`` (``repro.kernels.spmd``).
 
 ``fedpara_matmul`` is DIFFERENTIABLE: it is a ``jax.custom_vjp`` whose
 forward and backward are both fused Pallas kernels
@@ -20,7 +21,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import agg, blocks, fedpara_grad, ref, serve_matmul
+from repro.kernels import agg, blocks, fedpara_grad, ref, serve_matmul, spmd
 from repro.kernels.fedpara_compose import fedpara_compose as _compose
 
 
@@ -74,7 +75,7 @@ def fedpara_matmul(x, x1, y1, x2, y2, *, kind=None, use_tanh=False,
     f = fedpara_grad.differentiable_matmul(
         kind == "fedpara_tanh", kind == "pfedpara", bb, bm, bn, interpret,
         jnp.dtype(out_dtype).name if out_dtype is not None else None)
-    return f(x, x1, y1, x2, y2)
+    return spmd.call(f, x, x1, y1, x2, y2)
 
 
 def fedpara_matmul_vjp(x, x1, y1, x2, y2, dy, *, kind=None, use_tanh=False,
@@ -89,22 +90,33 @@ def fedpara_matmul_vjp(x, x1, y1, x2, y2, dy, *, kind=None, use_tanh=False,
         x1, y1, kind, use_tanh, interpret, block_b, block_m, block_n)
     kw = dict(use_tanh=kind == "fedpara_tanh", plus_one=kind == "pfedpara",
               block_b=bb, block_m=bm, block_n=bn, interpret=interpret)
-    dx = fedpara_grad.fedpara_dx(dy, x1, y1, x2, y2, out_dtype=x.dtype, **kw)
-    dx1, dx2 = fedpara_grad.fedpara_dx_factors(x, dy, x1, y1, x2, y2, **kw)
-    dy1, dy2 = fedpara_grad.fedpara_dy_factors(x, dy, x1, y1, x2, y2, **kw)
-    return dx, dx1, dy1, dx2, dy2
+
+    def vjp(x, x1, y1, x2, y2, dy):
+        dx = fedpara_grad.fedpara_dx(dy, x1, y1, x2, y2, out_dtype=x.dtype,
+                                     **kw)
+        dx1, dx2 = fedpara_grad.fedpara_dx_factors(x, dy, x1, y1, x2, y2,
+                                                   **kw)
+        dy1, dy2 = fedpara_grad.fedpara_dy_factors(x, dy, x1, y1, x2, y2,
+                                                   **kw)
+        return dx, dx1, dy1, dx2, dy2
+
+    return spmd.call(vjp, x, x1, y1, x2, y2, dy)
 
 
 def fedpara_compose(x1, y1, x2, y2, *, use_tanh=False, interpret=None, **kw):
     """W = (X1Y1ᵀ)⊙(X2Y2ᵀ) — tiled compose (serving pre-composition)."""
     interpret = _default_interpret() if interpret is None else interpret
-    return _compose(x1, y1, x2, y2, use_tanh=use_tanh, interpret=interpret, **kw)
+    return spmd.call(lambda *f: _compose(*f, use_tanh=use_tanh,
+                                         interpret=interpret, **kw),
+                     x1, y1, x2, y2)
 
 
 def pfedpara_compose(x1, y1, x2, y2, *, interpret=None, **kw):
     """W = (X1Y1ᵀ) ⊙ (X2Y2ᵀ + 1) — pFedPara compose."""
     interpret = _default_interpret() if interpret is None else interpret
-    return _compose(x1, y1, x2, y2, plus_one=True, interpret=interpret, **kw)
+    return spmd.call(lambda *f: _compose(*f, plus_one=True,
+                                         interpret=interpret, **kw),
+                     x1, y1, x2, y2)
 
 
 def _serve_blocks(m, n, r, block_b, block_m, block_n):
@@ -133,9 +145,9 @@ def w8_matmul(x, w, scale=None, *, interpret=None, block_b=None,
     interpret = _default_interpret() if interpret is None else interpret
     bb, bm, bn = _serve_blocks(w.shape[0], w.shape[1], 0,
                                block_b, block_m, block_n)
-    return serve_matmul.w8_matmul(
+    return spmd.call(lambda x, w, scale: serve_matmul.w8_matmul(
         x, w, scale, block_b=bb, block_m=bm, block_n=bn,
-        interpret=interpret, out_dtype=out_dtype)
+        interpret=interpret, out_dtype=out_dtype), x, w, scale)
 
 
 def cache_residual_matmul(x, w, scale, x2, y2, *, interpret=None,
@@ -159,9 +171,9 @@ def cache_residual_matmul(x, w, scale, x2, y2, *, interpret=None,
     interpret = _default_interpret() if interpret is None else interpret
     bb, bm, bn = _serve_blocks(w.shape[0], w.shape[1], x2.shape[-1],
                                block_b, block_m, block_n)
-    return serve_matmul.cache_residual_matmul(
-        x, w, scale, x2, y2, block_b=bb, block_m=bm, block_n=bn,
-        interpret=interpret, out_dtype=out_dtype)
+    return spmd.call(lambda *a: serve_matmul.cache_residual_matmul(
+        *a, block_b=bb, block_m=bm, block_n=bn, interpret=interpret,
+        out_dtype=out_dtype), x, w, scale, x2, y2)
 
 
 def fedpara_gram_decode(x, x1, y1, x2, y2, *, kind=None, out_dtype=None):

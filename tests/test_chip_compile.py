@@ -10,20 +10,27 @@ compiled path, not interpret mode, is what passed.
 Shapes: the Shakespeare LSTM's gate factors at ``LSTMConfig()`` widths
 (8x1024 and 256x1024 at the ranks its gamma gives) with the FL client
 batch, a (4096, 14336, r=64) bf16 FFN layer, the int8 upload fold at a
-16-client chunk and at one client, and the serve kernels.
+16-client chunk and at one client, and the serve kernels. On a v5e:2x2
+mesh: the LSTM's held-out loss and its gradient on a global model
+replicated over the chips, and the int8 fold of client-sharded wires
+into a replicated accumulator — XLA cannot partition a Mosaic kernel,
+so these compile only through ``repro.kernels.spmd``.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and the
 test workers import every test file.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.nn.recurrent import LSTMConfig, init_lstm
+from repro.nn.recurrent import LSTMConfig, init_lstm, lstm_loss
 
 CLIENT_BATCH = 32
 
@@ -64,6 +71,18 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("clients",))
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    # code that picks interpret mode by asking for the backend takes
+    # the chip's branch, as it does on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 def _compiled_text(f, *args) -> str:
@@ -141,4 +160,40 @@ def test_serve_kernel_compiles(one_chip, kernel):
         text = _compiled_text(
             lambda *a: ops.cache_residual_matmul(*a, interpret=False),
             x, w, scale, sds((m, r), jnp.bfloat16), sds((n, r), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("what", ["loss", "grad"])
+def test_pallas_lstm_on_replicated_mesh_compiles(four_chips,
+                                                 compiled_kernels, what):
+    # an FL server on a mesh holds its global model replicated over the
+    # chips; its held-out loss (and a sequential step) runs the fused
+    # layers on it
+    cfg = LSTMConfig()
+    cfg = dataclasses.replace(
+        cfg, param=dataclasses.replace(cfg.param, use_pallas=True))
+    rep = NamedSharding(four_chips, P())
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+        jax.eval_shape(lambda: init_lstm(jax.random.PRNGKey(0), cfg)))
+    test = {"tokens": np.zeros((256, 80), np.int32)}
+
+    def loss(p):
+        return lstm_loss(p, cfg, test)
+
+    text = _compiled_text(loss if what == "loss" else jax.grad(loss), params)
+    assert "tpu_custom_call" in text
+
+
+def test_int8_fold_on_mesh_compiles(four_chips, compiled_kernels):
+    # a chunk that does not divide over the mesh, and the async
+    # engine's arrival fold: client-sharded wires, replicated sum
+    n = 1000
+    rep, split = NamedSharding(four_chips, P()), NamedSharding(
+        four_chips, P("clients"))
+    text = _compiled_text(
+        lambda acc, q, c: ops.tree_dequant_acc(acc, q, c),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((16, n), jnp.int8, sharding=split),
+        jax.ShapeDtypeStruct((16,), jnp.float32, sharding=split))
     assert "tpu_custom_call" in text

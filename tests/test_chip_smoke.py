@@ -170,6 +170,26 @@ def test_fleet_children_refused_after_jax_import():
         fl_streaming.fleet_section((100,))
 
 
+def test_benchmark_csv_runs_fleet_sweep_before_jax():
+    # the stand-ins record whether JAX was loaded when the sweep ran, and
+    # what the fl_streaming group was handed
+    code = textwrap.dedent("""
+        import sys
+        from benchmarks import fl_streaming, run
+        fl_streaming.fleet_section = lambda: {"jax": "jax" in sys.modules}
+        fl_streaming.csv_rows = lambda fleet=None: [
+            ("fleet_seen", 0.0, repr(fleet))]
+        sys.argv = ["run", "--only", "stream", "--skip-roofline"]
+        run.main()
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, cwd=REPO, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "fleet_seen,0.0,{'jax': False}" in proc.stdout.splitlines()
+
+
 def test_train_runs_on_the_devices_it_is_given(monkeypatch):
     import jax
 

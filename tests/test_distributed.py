@@ -230,6 +230,49 @@ def test_streaming_engine_on_client_mesh():
     """)
 
 
+def test_kernels_on_replicated_mesh_operands():
+    """Pallas kernels whose operands sit on a multi-device mesh (a
+    server's replicated global model, client-sharded wires) run under a
+    replicated shard_map and give the single-device results: forward,
+    gradient through the custom VJP, and the int8 fold."""
+    run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.kernels import ops, spmd
+
+        mesh = Mesh(np.array(jax.devices()[:4]), ('clients',))
+        rep = NamedSharding(mesh, P())
+        k = jax.random.split(jax.random.PRNGKey(0), 5)
+        args = (jax.random.normal(k[0], (16, 64)),
+                jax.random.normal(k[1], (64, 8)),
+                jax.random.normal(k[2], (32, 8)),
+                jax.random.normal(k[3], (64, 8)),
+                jax.random.normal(k[4], (32, 8)))
+        loss = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.square(ops.fedpara_matmul(*a)).sum(),
+            argnums=(0, 1, 2, 3, 4)))
+        one, four = loss(*args), loss(*jax.device_put(args, rep))
+        for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(four)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert spmd.free_mesh(jax.device_put(args, rep))[1] == ('clients',)
+        assert spmd.free_mesh(args) == (None, ())
+        text = jax.jit(ops.fedpara_matmul).lower(
+            *jax.device_put(args, rep)).as_text()
+        assert 'manual_computation' in text or 'shard_map' in text
+
+        acc = jnp.zeros((100,))
+        q = jax.random.randint(k[0], (4, 100), -127, 128).astype(jnp.int8)
+        c = jnp.arange(1.0, 5.0)
+        fold = jax.jit(lambda a, q, c: ops.tree_dequant_acc(a, q, c))
+        want = fold(acc, q, c)
+        got = fold(jax.device_put(acc, rep),
+                   jax.device_put(q, NamedSharding(mesh, P('clients'))),
+                   jax.device_put(c, NamedSharding(mesh, P('clients'))))
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+        print('OK replicated kernels')
+    """, devices=4)
+
+
 def test_bucketed_pmean_subprocess():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
