@@ -179,6 +179,17 @@ class ChunkBatchSource:
             m[row, : n] = 1.0
         return m
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the whole ``(chunk * n_chunks, S, B, ...)`` batch
+        stack the fetches deliver, from shapes and dtypes alone: the
+        ``nbytes`` of the eager ``stack_client_epochs`` stack it
+        stands in for."""
+        row = self.S * self.batch * sum(
+            int(np.prod(self.data[k].shape[1:]))
+            * np.dtype(self.data[k].dtype).itemsize for k in self.keys)
+        return len(self.cids) * row
+
     def chunk_struct(self):
         """``jax.ShapeDtypeStruct`` tree of one fetched chunk — the
         ``pure_callback`` result signature."""

@@ -98,6 +98,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import parameterization as param_lib
 from repro.core import rank_policy
@@ -746,16 +747,28 @@ class FLServer:
         ``recover_frac`` of the participants and retries remain, a
         replacement cohort is re-sampled from a salted stream and the
         attempt's results are discarded. Only the accepted attempt's
-        writebacks, aggregation and wire charges commit."""
+        writebacks, aggregation and wire charges commit.
+
+        The round and its phases are recorded as ``jax.profiler`` spans
+        (``fl.round``, ``fl.round.<phase>``; see docs/engines.md), which
+        are inert while no profiler runs."""
+        with TraceAnnotation("fl.round"):
+            if self.scfg.engine == "async":
+                return self._run_async_round()
+            return self._run_sync_round()
+
+    def _run_sync_round(self) -> Dict:
+        """One round of the sequential, batched or streaming engine
+        (:meth:`run_round`), its phases recorded as profiler spans."""
         scfg = self.scfg
         plan = scfg.faults
-        if scfg.engine == "async":
-            return self._run_async_round()
-        sampled, mask, seeds, lr, probe, lat = self._select_round()
-        if not mask.any():   # everyone failed: skip round (fault tolerance)
-            self.round_idx += 1
-            return {"round": self.round_idx, "participants": 0, "skipped": True}
-        down_dec, down_bytes = self._encode_downlink(probe)
+        with TraceAnnotation("fl.round.select"):
+            sampled, mask, seeds, lr, probe, lat = self._select_round()
+            if not mask.any():   # everyone failed: skip round (fault tolerance)
+                self.round_idx += 1
+                return {"round": self.round_idx, "participants": 0,
+                        "skipped": True}
+            down_dec, down_bytes = self._encode_downlink(probe)
         attempt = 0
         while True:
             fault = (plan.draw(self.round_idx, len(sampled), attempt)
@@ -802,7 +815,8 @@ class FLServer:
                     sampled, mask, seeds, lr, _, lat = nxt
                     continue
             break
-        commit()
+        with TraceAnnotation("fl.round.commit"):
+            commit()
         # virtual seconds the sync barrier costs: the round completes
         # when its LAST arrival lands (the async engine's benchmark
         # baseline — see benchmarks/fl_async.py)
@@ -1093,61 +1107,70 @@ class FLServer:
         tier_idx = self._cohort_tiers(cids) if hetero else None
         arena = scfg.state_store == "arena"
 
-        if arena:
-            # ONE vectorized gather for the whole cohort: state and
-            # resident rows come off the device arena, params assemble
-            # from the broadcast — no per-client Python loop exists
-            self._ensure_arena()
-            rows = self.arena.rows_for(cids)
-            stacked_state, stacked_res = self.arena.gather(rows)
-            stacked_state = self._stacked_state_fixups(stacked_state, C,
-                                                       tier_idx)
-            from repro.fl.batch_engine import assemble_client_params
+        with TraceAnnotation("fl.round.arena_gather"):
+            if arena:
+                # ONE vectorized gather for the whole cohort: state and
+                # resident rows come off the device arena, params
+                # assemble from the broadcast — no per-client Python loop
+                self._ensure_arena()
+                rows = self.arena.rows_for(cids)
+                stacked_state, stacked_res = self.arena.gather(rows)
+                stacked_state = self._stacked_state_fixups(stacked_state, C,
+                                                           tier_idx)
+                from repro.fl.batch_engine import assemble_client_params
 
-            stacked_params = assemble_client_params(
-                down_dec, stacked_res, C, scfg.personalization,
-                FEDPER_LOCAL_KEYS)
-            if hetero:
-                fmask = jax.tree.map(
-                    lambda m: jnp.take(m, jnp.asarray(tier_idx, jnp.int32),
-                                       axis=0), tc["full_masks"])
-                stacked_params = param_lib.apply_rank_mask(stacked_params,
-                                                           fmask)
-        else:
-            full, states = [], []
-            for pos, cid in enumerate(cids):
-                params = self._client_full_params(cid, down_dec)
-                tier = int(tier_idx[pos]) if hetero else -1
+                stacked_params = assemble_client_params(
+                    down_dec, stacked_res, C, scfg.personalization,
+                    FEDPER_LOCAL_KEYS)
                 if hetero:
-                    params = param_lib.apply_rank_mask(
-                        params, tree_index(tc["full_masks"], tier))
-                full.append(params)
-                states.append(self._prep_client_state(cid, params, down_dec,
-                                                      tier=tier))
-            stacked_params = tree_stack(full)
-            stacked_state = tree_stack(states) if states and states[0] else {}
+                    fmask = jax.tree.map(
+                        lambda m: jnp.take(
+                            m, jnp.asarray(tier_idx, jnp.int32), axis=0),
+                        tc["full_masks"])
+                    stacked_params = param_lib.apply_rank_mask(
+                        stacked_params, fmask)
+            else:
+                full, states = [], []
+                for pos, cid in enumerate(cids):
+                    params = self._client_full_params(cid, down_dec)
+                    tier = int(tier_idx[pos]) if hetero else -1
+                    if hetero:
+                        params = param_lib.apply_rank_mask(
+                            params, tree_index(tc["full_masks"], tier))
+                    full.append(params)
+                    states.append(self._prep_client_state(
+                        cid, params, down_dec, tier=tier))
+                stacked_params = tree_stack(full)
+                stacked_state = (tree_stack(states) if states and states[0]
+                                 else {})
 
-        batches, step_mask = stack_client_epochs(
-            self.data, self.partitions, cids, self.ccfg.batch,
-            self.ccfg.epochs, seeds)
+        with TraceAnnotation("fl.round.stack_batches"):
+            batches, step_mask = stack_client_epochs(
+                self.data, self.partitions, cids, self.ccfg.batch,
+                self.ccfg.epochs, seeds)
+        batch_bytes = sum(int(b.nbytes) for b in batches.values())
         sizes = np.array([len(self.partitions[c]) for c in cids], np.float32)
         agg_target = (self.global_params if scfg.personalization == "none"
                       else self._download_payload(-1))
+        with TraceAnnotation("fl.round.put_batches"):
+            batches = jax.tree.map(jnp.asarray, batches)
 
-        (new_p, new_state, upload, local, last_loss, n_steps, new_global,
-         new_server_state, valid_dev) = self._engine.run(
-            stacked_params, stacked_state, batches, step_mask,
-            mask, sizes, lr, self._quant_keys(C),
-            self.server_state, agg_target, down_dec,
-            tier_idx=tier_idx,
-            tier_masks=tc["payload_masks"] if hetero else None,
-            fault=faults_lib.device_fault_args(fault),
-            stale_ref=(None if fault is None else
-                       (self._stale_ref if self._stale_ref is not None
-                        else down_dec)))
+        with TraceAnnotation("fl.round.dispatch"):
+            (new_p, new_state, upload, local, last_loss, n_steps, new_global,
+             new_server_state, valid_dev) = self._engine.run(
+                stacked_params, stacked_state, batches, step_mask,
+                mask, sizes, lr, self._quant_keys(C),
+                self.server_state, agg_target, down_dec,
+                tier_idx=tier_idx,
+                tier_masks=tc["payload_masks"] if hetero else None,
+                fault=faults_lib.device_fault_args(fault),
+                stale_ref=(None if fault is None else
+                           (self._stale_ref if self._stale_ref is not None
+                            else down_dec)))
 
         arrived = np.nonzero(mask)[0]
-        valid = np.asarray(valid_dev, np.float32)
+        with TraceAnnotation("fl.round.wait"):
+            valid = np.asarray(valid_dev, np.float32)
 
         def commit():
             if arena:
@@ -1182,6 +1205,7 @@ class FLServer:
             "nonfinite_losses": nonfinite,
             "down_bytes": rd,
             "up_bytes": ru,
+            "host_batch_bytes": batch_bytes,
             "lr": lr,
         }
         return rec, commit, valid
@@ -1211,56 +1235,63 @@ class FLServer:
         tier_pad = self._cohort_tiers(cids_pad) if hetero else None
         arena = scfg.state_store == "arena"
 
-        if arena:
-            # ONE vectorized cohort gather off the device arena (pad
-            # slots address the scratch row); params assemble inside
-            # the scan step from the broadcast + gathered residents
-            self._ensure_arena()
-            rows = self.arena.rows_for(cids, pad=pad)
-            stacked_state, stacked_res = self.arena.gather(rows)
-            stacked_state = self._stacked_state_fixups(
-                stacked_state, C + pad, tier_pad)
-        else:
-            states, residents = [], []
-            for pos, cid in enumerate(cids_pad):
-                params = self._client_full_params(cid, down_dec)
-                states.append(self._prep_client_state(
-                    cid, params, down_dec,
-                    tier=int(tier_pad[pos]) if hetero else -1))
-                if mode == "pfedpara":
-                    residents.append(comm.split_pfedpara(params)[1])
-                elif mode == "fedper":
-                    residents.append({k: params[k] for k in FEDPER_LOCAL_KEYS
-                                      if k in params})
-                elif mode == "local":
-                    residents.append(params)
-            stacked_state = tree_stack(states) if states and states[0] else {}
-            stacked_res = tree_stack(residents) if residents else None
+        with TraceAnnotation("fl.round.arena_gather"):
+            if arena:
+                # ONE vectorized cohort gather off the device arena (pad
+                # slots address the scratch row); params assemble inside
+                # the scan step from the broadcast + gathered residents
+                self._ensure_arena()
+                rows = self.arena.rows_for(cids, pad=pad)
+                stacked_state, stacked_res = self.arena.gather(rows)
+                stacked_state = self._stacked_state_fixups(
+                    stacked_state, C + pad, tier_pad)
+            else:
+                states, residents = [], []
+                for pos, cid in enumerate(cids_pad):
+                    params = self._client_full_params(cid, down_dec)
+                    states.append(self._prep_client_state(
+                        cid, params, down_dec,
+                        tier=int(tier_pad[pos]) if hetero else -1))
+                    if mode == "pfedpara":
+                        residents.append(comm.split_pfedpara(params)[1])
+                    elif mode == "fedper":
+                        residents.append({k: params[k]
+                                          for k in FEDPER_LOCAL_KEYS
+                                          if k in params})
+                    elif mode == "local":
+                        residents.append(params)
+                stacked_state = (tree_stack(states) if states and states[0]
+                                 else {})
+                stacked_res = tree_stack(residents) if residents else None
 
-        # one round-wide step axis so every chunk (and every later round
-        # with the same cohort shape) shares a compiled program
-        S = max(client_step_count(len(self.partitions[c]), self.ccfg.batch,
-                                  self.ccfg.epochs) for c in cids)
-        data_source = None
-        if scfg.data_stream == "chunked":
-            # lazy per-chunk data: the scan step's host callback
-            # materializes one chunk's batches at a time — the cohort's
-            # (C, S, B, ...) stack never exists on the host
-            data_source = ChunkBatchSource(
-                self.data, self.partitions, cids, self.ccfg.batch,
-                self.ccfg.epochs, [int(s) for s in seeds],
-                chunk=chunk, n_chunks=n_chunks, pad_steps=max(S, 1))
-            batches_xs = None
-            step_mask = data_source.step_mask()
-        else:
-            # pad slots are pre-sized into the stacked allocation
-            # (zero batches, fully masked) — never concatenated in
-            batches, step_mask = stack_client_epochs(
-                self.data, self.partitions, cids, self.ccfg.batch,
-                self.ccfg.epochs, [int(s) for s in seeds],
-                pad_steps=max(S, 1), pad_clients=pad)
-            batches_xs = to_chunks(jax.tree.map(jnp.asarray, batches),
-                                   n_chunks, chunk)
+        with TraceAnnotation("fl.round.stack_batches"):
+            # one round-wide step axis so every chunk (and every later
+            # round with the same cohort shape) shares a compiled program
+            S = max(client_step_count(len(self.partitions[c]),
+                                      self.ccfg.batch, self.ccfg.epochs)
+                    for c in cids)
+            data_source = batches = None
+            if scfg.data_stream == "chunked":
+                # lazy per-chunk data: the scan step's host callback
+                # materializes one chunk's batches at a time — the
+                # cohort's (C, S, B, ...) stack never exists on the host
+                data_source = ChunkBatchSource(
+                    self.data, self.partitions, cids, self.ccfg.batch,
+                    self.ccfg.epochs, [int(s) for s in seeds],
+                    chunk=chunk, n_chunks=n_chunks, pad_steps=max(S, 1))
+                step_mask = data_source.step_mask()
+                batch_bytes = data_source.nbytes
+            else:
+                # pad slots are pre-sized into the stacked allocation
+                # (zero batches, fully masked) — never concatenated in
+                batches, step_mask = stack_client_epochs(
+                    self.data, self.partitions, cids, self.ccfg.batch,
+                    self.ccfg.epochs, [int(s) for s in seeds],
+                    pad_steps=max(S, 1), pad_clients=pad)
+                batch_bytes = sum(int(b.nbytes) for b in batches.values())
+        with TraceAnnotation("fl.round.put_batches"):
+            batches_xs = (None if batches is None else to_chunks(
+                jax.tree.map(jnp.asarray, batches), n_chunks, chunk))
         mask_pad = np.zeros(C + pad, np.float32)
         mask_pad[:C] = mask
         sizes_pad = np.zeros(C + pad, np.float32)
@@ -1291,28 +1322,31 @@ class FLServer:
             stale_ref = (self._stale_ref if self._stale_ref is not None
                          else down_dec)
 
-        (state_ys, local_ys, loss_ys, _steps, new_global,
-         new_server_state, valid_ys) = self._stream.run(
-            to_chunks(stacked_state, n_chunks, chunk),
-            to_chunks(stacked_res, n_chunks, chunk)
-            if stacked_res is not None else None,
-            batches_xs,
-            to_chunks(jnp.asarray(step_mask, jnp.float32), n_chunks, chunk),
-            to_chunks(jnp.asarray(mask_pad), n_chunks, chunk),
-            to_chunks(jnp.asarray(sizes_pad), n_chunks, chunk),
-            to_chunks(self._quant_keys(C + pad), n_chunks, chunk),
-            lr, self.server_state, agg_target, down_dec,
-            tier_xs=(to_chunks(jnp.asarray(tier_pad), n_chunks, chunk)
-                     if hetero else None),
-            tier_payload_masks=tc["payload_masks"] if hetero else None,
-            tier_full_masks=tc["full_masks"] if hetero else None,
-            data_source=data_source,
-            fault_xs=fault_xs, stale_ref=stale_ref)
+        with TraceAnnotation("fl.round.dispatch"):
+            (state_ys, local_ys, loss_ys, _steps, new_global,
+             new_server_state, valid_ys) = self._stream.run(
+                to_chunks(stacked_state, n_chunks, chunk),
+                to_chunks(stacked_res, n_chunks, chunk)
+                if stacked_res is not None else None,
+                batches_xs,
+                to_chunks(jnp.asarray(step_mask, jnp.float32), n_chunks,
+                          chunk),
+                to_chunks(jnp.asarray(mask_pad), n_chunks, chunk),
+                to_chunks(jnp.asarray(sizes_pad), n_chunks, chunk),
+                to_chunks(self._quant_keys(C + pad), n_chunks, chunk),
+                lr, self.server_state, agg_target, down_dec,
+                tier_xs=(to_chunks(jnp.asarray(tier_pad), n_chunks, chunk)
+                         if hetero else None),
+                tier_payload_masks=tc["payload_masks"] if hetero else None,
+                tier_full_masks=tc["full_masks"] if hetero else None,
+                data_source=data_source,
+                fault_xs=fault_xs, stale_ref=stale_ref)
 
         new_state = from_chunks(state_ys) if state_ys else {}
         local = from_chunks(local_ys) if local_ys is not None else None
         arrived = np.nonzero(mask)[0]
-        valid = np.asarray(from_chunks(valid_ys), np.float32)[:C]
+        with TraceAnnotation("fl.round.wait"):
+            valid = np.asarray(from_chunks(valid_ys), np.float32)[:C]
 
         def commit():
             if arena:
@@ -1345,6 +1379,7 @@ class FLServer:
             "nonfinite_losses": nonfinite,
             "down_bytes": rd,
             "up_bytes": ru,
+            "host_batch_bytes": batch_bytes,
             "lr": lr,
         }
         return rec, commit, valid

@@ -118,6 +118,7 @@ def dequant_acc(
         out_shape=jax.ShapeDtypeStruct((1, Lp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bl), jnp.float32)],
         input_output_aliases={2: 0},
+        name="dequant_acc",
         interpret=interpret,
     ), coeffp, qp, accp)
     return out[0, :L]

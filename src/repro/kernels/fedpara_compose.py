@@ -93,6 +93,7 @@ def fedpara_compose(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+        name="fedpara_compose",
         interpret=interpret,
     )(x1p, y1p, x2p, y2p)
     return out[:m, :n]
@@ -138,6 +139,7 @@ def _fedpara_compose_batched(x1, y1, x2, y2, *, use_tanh, plus_one,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda c, i, j: (c, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, mp, np_), out_dtype),
+        name="fedpara_compose",
         interpret=interpret,
     )(x1p, y1p, x2p, y2p)
     return out[:, :m, :n]

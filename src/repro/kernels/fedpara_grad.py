@@ -181,6 +181,7 @@ def fedpara_dx(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bb, bm), jnp.float32)],
+        name="fedpara_dx",
         interpret=interpret,
     )(dyp, x1p, y1p, x2p, y2p)
     return out[..., :b, :m]
@@ -319,6 +320,7 @@ def _dfactors(x, dy, x1, y1, x2, y2, *, side: str, use_tanh, plus_one,
             pltpu.VMEM((out_blk, r), jnp.float32),
             pltpu.VMEM((out_blk, r), jnp.float32),
         ],
+        name=f"fedpara_d{side}_factors",
         interpret=interpret,
     )(xp, dyp, x1p, y1p, x2p, y2p)
     rows = m if side == "x" else n

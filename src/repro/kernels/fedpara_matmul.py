@@ -169,6 +169,7 @@ def fedpara_matmul(
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="fedpara_matmul",
         interpret=interpret,
     )(xp, x1p, y1p, x2p, y2p)
     return out[:b, :n]
@@ -203,6 +204,7 @@ def _fedpara_matmul_batched(x, x1, y1, x2, y2, *, use_tanh, plus_one,
         out_specs=pl.BlockSpec((1, bb, bn), lambda c, i, j, k: (c, i, j)),
         out_shape=jax.ShapeDtypeStruct((C, bp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="fedpara_matmul",
         interpret=interpret,
     )(xp, x1p, y1p, x2p, y2p)
     return out[:, :b, :n]

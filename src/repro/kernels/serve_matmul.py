@@ -151,6 +151,7 @@ def w8_matmul(x, w, scale=None, *, block_b: int = 64, block_m: int = 256,
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="w8_matmul",
         interpret=interpret,
     )(xp, wp, sp)
     return out[:b, :n]
@@ -201,6 +202,7 @@ def cache_residual_matmul(x, w, scale, x2, y2, *, block_b: int = 64,
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="cache_residual_matmul",
         interpret=interpret,
     )(xp, wp, sp, x2p, y2p)
     return out[:b, :n]
@@ -236,6 +238,7 @@ def _cache_residual_users(x, w, scale, x2, y2, *, block_b, block_m, block_n,
         out_specs=pl.BlockSpec((1, bb, bn), lambda u, i, j, k: (u, i, j)),
         out_shape=jax.ShapeDtypeStruct((U, tp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
+        name="cache_residual_matmul",
         interpret=interpret,
     )(xp, wp, sp, x2p, y2p)
     return out[:, :t, :n]
