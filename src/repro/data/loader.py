@@ -1,13 +1,21 @@
 """Batch loaders: local epochs for FL clients + sharded global batches
 for the pod trainer (deterministic, resumable — the checkpoint stores
-the stream position so restarts continue mid-epoch)."""
+the stream position so restarts continue mid-epoch).
+
+A round's client batch stack is described by index first
+(:func:`epoch_indices`), then gathered on the device from a resident
+copy of the dataset (:class:`DeviceDataset`) or on the host
+(:func:`gather_host`), bit for bit alike."""
 from __future__ import annotations
 
+import functools
 import threading
 import queue as queue_mod
 from dataclasses import dataclass
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -23,18 +31,40 @@ def _epoch_rng(seed: int) -> np.random.RandomState:
     return np.random.RandomState(np.random.SeedSequence(s).generate_state(4))
 
 
+def client_step_ids(idx: np.ndarray, batch: int, epochs: int,
+                    seed: int) -> np.ndarray:
+    """One client's local-epoch order as an ``(steps, batch)`` array of
+    the global sample ids in ``idx``: each epoch a fresh permutation
+    (``_epoch_rng(seed)``) cut into full batches. A tiny client (fewer
+    than ``batch`` samples) takes one batch an epoch, its permuted ids
+    wrapped to ``batch`` (``np.resize``); an empty client, none."""
+    idx = np.asarray(idx)
+    n = len(idx)
+    if n == 0:
+        return np.zeros((0, batch), idx.dtype)
+    rng = _epoch_rng(seed)
+    per_epoch = n // batch if n >= batch else 1
+    out = np.empty((epochs * per_epoch, batch), idx.dtype)
+    for e in range(epochs):
+        order = rng.permutation(n)
+        if n >= batch:
+            out[e * per_epoch:(e + 1) * per_epoch] = idx[
+                order[: per_epoch * batch]].reshape(per_epoch, batch)
+        else:
+            out[e] = np.resize(idx[order], batch)
+    return out
+
+
 def client_epochs(data: Dict[str, np.ndarray], idx: np.ndarray, batch: int,
                   epochs: int, seed: int) -> Iterator[Dict[str, np.ndarray]]:
-    """Minibatch iterator over one client's local data for E epochs."""
-    rng = _epoch_rng(seed)
-    for _ in range(epochs):
-        order = rng.permutation(len(idx))
-        for i in range(0, len(order) - batch + 1, batch):
-            sel = idx[order[i: i + batch]]
-            yield {k: v[sel] for k, v in data.items()}
-        if 0 < len(order) < batch:  # tiny client: one short batch per epoch
-            sel = idx[order]
-            yield {k: v[sel] for k, v in data.items()}
+    """Minibatch iterator over one client's local data for E epochs, in
+    the order of :func:`client_step_ids`; a tiny client's batches keep
+    their short length here (the sequential reference trains on them
+    as they are)."""
+    n = min(len(idx), batch)
+    for sel in client_step_ids(idx, batch, epochs, seed):
+        sel = sel[:n]
+        yield {k: v[sel] for k, v in data.items()}
 
 
 def client_step_count(n_samples: int, batch: int, epochs: int) -> int:
@@ -47,39 +77,56 @@ def client_step_count(n_samples: int, batch: int, epochs: int) -> int:
     return per_epoch * epochs
 
 
-def _client_steps(data: Dict[str, np.ndarray], idx: np.ndarray, batch: int,
-                  epochs: int, seed: int) -> List[Dict[str, np.ndarray]]:
-    """One client's materialized local-epoch minibatch list (empty for
-    clients with no samples)."""
-    return (list(client_epochs(data, idx, batch, epochs, seed))
-            if len(idx) else [])
+def epoch_indices(
+    partitions: Sequence[np.ndarray],
+    cids: Sequence[int],
+    batch: int,
+    epochs: int,
+    seeds: Sequence[int],
+    pad_steps: Optional[int] = None,
+    pad_clients: int = 0,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The sampled clients' batch stack described by index: the one
+    builder of every stack, on the host or on the device.
+
+    Returns ``(ids, zero_rows, step_mask)``: ``ids`` an int32
+    ``(C + pad_clients, S, B)`` array of global sample ids (row ``c``,
+    step ``s`` is :func:`client_step_ids`'s step ``s % steps`` — a
+    client with fewer than S steps repeats its own batches, masked
+    out); ``zero_rows`` a bool ``(C + pad_clients,)`` flag on the rows
+    whose batches are all zeros (empty clients and the
+    ``pad_clients`` pad rows, whose ids are 0); ``step_mask`` a float32
+    ``(C + pad_clients, S)`` array with 1.0 on real steps. S is the
+    largest real step count, or ``pad_steps`` where given (it must
+    cover every client's real step count)."""
+    per_client = [client_step_ids(partitions[cid], batch, epochs, seed)
+                  for cid, seed in zip(cids, seeds)]
+    C = len(per_client)
+    S = max(1, max((len(s) for s in per_client), default=0))
+    if pad_steps is not None:
+        if pad_steps < S:
+            raise ValueError(
+                f"pad_steps={pad_steps} below max real step count {S}")
+        S = max(1, pad_steps)
+    ids = np.zeros((C + pad_clients, S, batch), np.int32)
+    zero_rows = np.ones(C + pad_clients, bool)
+    step_mask = np.zeros((C + pad_clients, S), np.float32)
+    for c, steps in enumerate(per_client):
+        if len(steps):
+            ids[c] = steps[np.arange(S) % len(steps)]
+            zero_rows[c] = False
+            step_mask[c, : len(steps)] = 1.0
+    return ids, zero_rows, step_mask
 
 
-def _pad_batch(b: Dict[str, np.ndarray], batch: int,
-               keys: Sequence[str]) -> Dict[str, np.ndarray]:
-    """Wrap a tiny client's short batch up to the full batch size."""
-    n = len(b[keys[0]])
-    if n == batch:
-        return b
-    sel = np.resize(np.arange(n), batch)  # wrap tiny-client batches
-    return {k: v[sel] for k, v in b.items()}
-
-
-def _fill_row(out: Dict[str, np.ndarray], step_mask: np.ndarray, row: int,
-              steps: List[Dict[str, np.ndarray]], S: int, batch: int,
-              keys: Sequence[str]) -> None:
-    """Write one client's steps into row ``row`` of the stacked output,
-    right-padding by repeating its own batches. Shared by the eager
-    stack (``stack_client_epochs``) and the lazy per-chunk source
-    (:class:`ChunkBatchSource`) so the two are bit-identical."""
-    if not steps:  # empty client: all-padding (zeros), mask stays 0
-        return
-    steps = [_pad_batch(b, batch, keys) for b in steps]
-    step_mask[row, : len(steps)] = 1.0
-    for s in range(S):
-        b = steps[s] if s < len(steps) else steps[s % len(steps)]
-        for k in keys:
-            out[k][row, s] = b[k]
+def gather_host(data: Dict[str, np.ndarray], ids: np.ndarray,
+                zero_rows: np.ndarray) -> Dict[str, np.ndarray]:
+    """The ``(C, S, B, ...)`` batch stack of :func:`epoch_indices`'s
+    ``ids`` built on the host, the flagged rows zeroed."""
+    out = {k: v[ids] for k, v in data.items()}
+    for v in out.values():
+        v[zero_rows] = 0
+    return out
 
 
 def stack_client_epochs(
@@ -93,7 +140,7 @@ def stack_client_epochs(
     pad_clients: int = 0,
 ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
     """Materialize every sampled client's ``client_epochs`` stream into one
-    stacked batch tensor for the client-batched engine.
+    stacked batch tensor for the client-batched engine, on the host.
 
     Returns ``(batches, step_mask)`` where ``batches[k]`` has shape
     ``(C, S, B, ...)`` — C sampled clients, S = max local steps across the
@@ -108,26 +155,76 @@ def stack_client_epochs(
     ``pad_steps`` fixes the step axis S explicitly (must cover every
     client's real step count) so chunked callers keep one shape
     signature across chunks and rounds. ``pad_clients`` appends that
-    many all-zero, fully-masked client rows, pre-sized in the output
-    allocation — the streaming engine's chunk padding — so callers
-    never concatenate a second full-cohort copy."""
-    per_client = [_client_steps(data, partitions[cid], batch, epochs, seed)
-                  for cid, seed in zip(cids, seeds)]
-    C = len(per_client)
-    S = max(1, max(len(s) for s in per_client))
-    if pad_steps is not None:
-        if pad_steps < S:
-            raise ValueError(
-                f"pad_steps={pad_steps} below max real step count {S}")
-        S = max(1, pad_steps)
-    keys = list(data.keys())
+    many all-zero, fully-masked client rows. The rows are those of
+    :func:`epoch_indices`, as :class:`DeviceDataset` gathers them on
+    the device."""
+    ids, zero_rows, step_mask = epoch_indices(
+        partitions, cids, batch, epochs, seeds, pad_steps, pad_clients)
+    return gather_host(data, ids, zero_rows), step_mask
 
-    step_mask = np.zeros((C + pad_clients, S), np.float32)
-    out = {k: np.zeros((C + pad_clients, S, batch) + data[k].shape[1:],
-                       data[k].dtype) for k in keys}
-    for c, steps in enumerate(per_client):
-        _fill_row(out, step_mask, c, steps, S, batch, keys)
-    return out, step_mask
+
+def data_bytes(data: Dict[str, np.ndarray]) -> int:
+    """Bytes of a client dataset, from its arrays' shapes and dtypes."""
+    return sum(int(v.nbytes) for v in data.values())
+
+
+def device_data_budget(device) -> Optional[int]:
+    """Bytes of ``device``'s memory a resident client dataset may take:
+    a quarter of the limit the device reports, leaving the rest to the
+    round programs; ``None`` where it reports none (the CPU)."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    return None if limit is None else int(limit) // 4
+
+
+@functools.partial(jax.jit, static_argnames=("row_shapes",))
+def _gather_rows(arrays, ids, zero_rows, row_shapes):
+    """Device half of :func:`gather_host`: rows ``ids`` of every array,
+    the flagged client rows set to exact zeros, each sample reshaped to
+    ``row_shapes[k]``."""
+    out = {}
+    for k, shape in row_shapes:
+        rows = jnp.take(arrays[k], ids, axis=0, mode="clip")
+        keep = ~zero_rows.reshape((-1,) + (1,) * (rows.ndim - 1))
+        rows = jnp.where(keep, rows, jnp.zeros((), rows.dtype))
+        out[k] = rows.reshape(ids.shape + shape)
+    return out
+
+
+class DeviceDataset:
+    """A client dataset resident on the device, and the gather that
+    builds a round's batch stack there from :func:`epoch_indices`: only
+    the index array crosses from the host each round. Dtypes stay as
+    given. Samples of more than one axis are kept flat (``(N, F)``), so
+    the gather reads whole unpadded rows, and take their shape again in
+    the gather's output. Under a mesh the copy is replicated over it.
+    ``DeviceDataset.fits(data, device)`` says whether the dataset is
+    within :func:`device_data_budget`."""
+
+    def __init__(self, data: Dict[str, np.ndarray], mesh=None):
+        sharding = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            sharding = NamedSharding(mesh, PartitionSpec())
+        self.row_shapes = tuple((k, v.shape[1:]) for k, v in data.items())
+        self.arrays = {
+            k: jax.device_put(v.reshape(len(v), -1) if v.ndim > 2 else v,
+                              sharding)
+            for k, v in data.items()}
+
+    @staticmethod
+    def fits(data: Dict[str, np.ndarray], device) -> bool:
+        """Whether ``data`` is within ``device``'s
+        :func:`device_data_budget` (always, where it reports none)."""
+        budget = device_data_budget(device)
+        return budget is None or data_bytes(data) <= budget
+
+    def gather(self, ids: np.ndarray,
+               zero_rows: np.ndarray) -> Dict[str, jax.Array]:
+        """The ``(C, S, B, ...)`` stack :func:`gather_host` would build,
+        bit for bit, as device arrays."""
+        return _gather_rows(self.arrays, jnp.asarray(ids),
+                            jnp.asarray(zero_rows), self.row_shapes)
 
 
 class ChunkBatchSource:
@@ -141,12 +238,12 @@ class ChunkBatchSource:
     :meth:`fetch` through ``jax.pure_callback``, so host batch memory
     peaks at O(chunk · S · B), whatever the cohort size.
 
-    Rows are filled by the same ``_fill_row`` helper as the eager
+    Rows come from the same :func:`epoch_indices` builder as the eager
     stack, so chunk ``i`` of this source is bit-identical to rows
     ``[i*chunk, (i+1)*chunk)`` of ``stack_client_epochs`` with matching
     ``pad_steps`` / ``pad_clients`` — the eager/lazy parity tests hold
-    the two together. Pad slots are encoded as client id ``-1`` (zero
-    batches, zero mask).
+    the two together. Pad slots, all at the end, are encoded as client
+    id ``-1`` (zero batches, zero mask).
     """
 
     def __init__(self, data: Dict[str, np.ndarray],
@@ -193,8 +290,6 @@ class ChunkBatchSource:
     def chunk_struct(self):
         """``jax.ShapeDtypeStruct`` tree of one fetched chunk — the
         ``pure_callback`` result signature."""
-        import jax
-
         return {k: jax.ShapeDtypeStruct(
             (self.chunk, self.S, self.batch) + self.data[k].shape[1:],
             self.data[k].dtype) for k in self.keys}
@@ -203,19 +298,12 @@ class ChunkBatchSource:
         """Materialize chunk ``chunk_idx``'s ``(chunk, S, B, ...)``
         batches (called from the scan step's host callback)."""
         lo = int(chunk_idx) * self.chunk
-        out = {k: np.zeros(
-            (self.chunk, self.S, self.batch) + self.data[k].shape[1:],
-            self.data[k].dtype) for k in self.keys}
-        mask = np.zeros((self.chunk, self.S), np.float32)
-        for j in range(self.chunk):
-            cid = self.cids[lo + j]
-            if cid < 0:
-                continue
-            steps = _client_steps(self.data, self.partitions[cid],
-                                  self.batch, self.epochs,
-                                  self.seeds[lo + j])
-            _fill_row(out, mask, j, steps, self.S, self.batch, self.keys)
-        return out
+        real = [c for c in self.cids[lo: lo + self.chunk] if c >= 0]
+        ids, zero_rows, _ = epoch_indices(
+            self.partitions, real, self.batch, self.epochs,
+            self.seeds[lo: lo + len(real)], pad_steps=self.S,
+            pad_clients=self.chunk - len(real))
+        return gather_host(self.data, ids, zero_rows)
 
 
 @dataclass

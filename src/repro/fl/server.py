@@ -91,6 +91,7 @@ it to fp32 tolerance with bitwise-identical arrival masks.
 """
 from __future__ import annotations
 
+import functools
 import heapq
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
@@ -102,7 +103,8 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import parameterization as param_lib
 from repro.core import rank_policy
-from repro.data.loader import client_epochs, stack_client_epochs
+from repro.data.loader import (DeviceDataset, client_epochs, data_bytes,
+                               epoch_indices, gather_host)
 from repro.fl import codecs, comm
 from repro.fl import faults as faults_lib
 from repro.fl.arrivals import arrival_events, arrival_mask, fold_crashes
@@ -369,6 +371,7 @@ class FLServer:
         self._stale_ref: Any = None   # previous decoded broadcast (what a
                                       # stale-replay fault re-uploads)
         self.arena = None   # created lazily at the first arena-mode round
+        self._device_data = None   # uploaded lazily at the first stack
         self._mesh, self._mesh_axis = mesh, mesh_axis
         self._engine = None
         self._stream = None
@@ -1094,6 +1097,40 @@ class FLServer:
                     state["_ef_up"], pmask)
         return state
 
+    # ----------------------------------------------------- batch stacks
+    def _device_dataset(self) -> Optional[DeviceDataset]:
+        """The client dataset on the device, uploaded on first use
+        (replicated over the mesh, if any) where it fits
+        ``DeviceDataset.fits`` on the first device; ``None`` where it
+        does not, and the host stacks each round's batches."""
+        if self._device_data is None:
+            device = (self._mesh.devices.flat[0] if self._mesh is not None
+                      else jax.devices()[0])
+            self._device_data = (DeviceDataset(self.data, self._mesh)
+                                 if DeviceDataset.fits(self.data, device)
+                                 else False)
+        return self._device_data or None
+
+    def _build_batches(self, cids, seeds, pad_clients=0):
+        """The host's part of a round's ``(C + pad_clients, S, B, ...)``
+        batch stack (``epoch_indices``). Returns ``(put, step_mask,
+        gather, host_bytes)``: ``put()`` gives the stack on the device,
+        gathered there from the resident dataset (``gather`` is
+        ``"device"``, and the host built only the int32 index array) or
+        copied from the stack the host built (``"host"``);
+        ``host_bytes`` counts what the host built, the step mask and
+        zero-row flag not counted."""
+        ids, zero_rows, step_mask = epoch_indices(
+            self.partitions, cids, self.ccfg.batch, self.ccfg.epochs,
+            [int(s) for s in seeds], pad_clients=pad_clients)
+        dev = self._device_dataset()
+        if dev is not None:
+            return (functools.partial(dev.gather, ids, zero_rows),
+                    step_mask, "device", int(ids.nbytes))
+        batches = gather_host(self.data, ids, zero_rows)
+        return (functools.partial(jax.tree.map, jnp.asarray, batches),
+                step_mask, "host", data_bytes(batches))
+
     # ------------------------------------------------ batched engine
     def _run_round_batched(self, sampled, mask, seeds, lr, down_dec,
                            down_bytes, sel_mask=None, fault=None):
@@ -1145,15 +1182,13 @@ class FLServer:
                                  else {})
 
         with TraceAnnotation("fl.round.stack_batches"):
-            batches, step_mask = stack_client_epochs(
-                self.data, self.partitions, cids, self.ccfg.batch,
-                self.ccfg.epochs, seeds)
-        batch_bytes = sum(int(b.nbytes) for b in batches.values())
+            put, step_mask, gather, batch_bytes = self._build_batches(
+                cids, seeds)
         sizes = np.array([len(self.partitions[c]) for c in cids], np.float32)
         agg_target = (self.global_params if scfg.personalization == "none"
                       else self._download_payload(-1))
         with TraceAnnotation("fl.round.put_batches"):
-            batches = jax.tree.map(jnp.asarray, batches)
+            batches = put()
 
         with TraceAnnotation("fl.round.dispatch"):
             (new_p, new_state, upload, local, last_loss, n_steps, new_global,
@@ -1206,6 +1241,7 @@ class FLServer:
             "down_bytes": rd,
             "up_bytes": ru,
             "host_batch_bytes": batch_bytes,
+            "batch_gather": gather,
             "lr": lr,
         }
         return rec, commit, valid
@@ -1265,33 +1301,31 @@ class FLServer:
                 stacked_res = tree_stack(residents) if residents else None
 
         with TraceAnnotation("fl.round.stack_batches"):
-            # one round-wide step axis so every chunk (and every later
-            # round with the same cohort shape) shares a compiled program
-            S = max(client_step_count(len(self.partitions[c]),
-                                      self.ccfg.batch, self.ccfg.epochs)
-                    for c in cids)
-            data_source = batches = None
+            data_source = put = None
             if scfg.data_stream == "chunked":
                 # lazy per-chunk data: the scan step's host callback
                 # materializes one chunk's batches at a time — the
-                # cohort's (C, S, B, ...) stack never exists on the host
+                # cohort's (C, S, B, ...) stack never exists on the host.
+                # One round-wide step axis, as the eager stack has, so
+                # every chunk shares a compiled program
+                S = max(client_step_count(len(self.partitions[c]),
+                                          self.ccfg.batch, self.ccfg.epochs)
+                        for c in cids)
                 data_source = ChunkBatchSource(
                     self.data, self.partitions, cids, self.ccfg.batch,
                     self.ccfg.epochs, [int(s) for s in seeds],
                     chunk=chunk, n_chunks=n_chunks, pad_steps=max(S, 1))
                 step_mask = data_source.step_mask()
                 batch_bytes = data_source.nbytes
+                gather = "host"
             else:
-                # pad slots are pre-sized into the stacked allocation
-                # (zero batches, fully masked) — never concatenated in
-                batches, step_mask = stack_client_epochs(
-                    self.data, self.partitions, cids, self.ccfg.batch,
-                    self.ccfg.epochs, [int(s) for s in seeds],
-                    pad_steps=max(S, 1), pad_clients=pad)
-                batch_bytes = sum(int(b.nbytes) for b in batches.values())
+                # pad slots are zero rows of the one stack (zero
+                # batches, fully masked) — never concatenated in
+                put, step_mask, gather, batch_bytes = self._build_batches(
+                    cids, seeds, pad_clients=pad)
         with TraceAnnotation("fl.round.put_batches"):
-            batches_xs = (None if batches is None else to_chunks(
-                jax.tree.map(jnp.asarray, batches), n_chunks, chunk))
+            batches_xs = (None if put is None
+                          else to_chunks(put(), n_chunks, chunk))
         mask_pad = np.zeros(C + pad, np.float32)
         mask_pad[:C] = mask
         sizes_pad = np.zeros(C + pad, np.float32)
@@ -1380,6 +1414,7 @@ class FLServer:
             "down_bytes": rd,
             "up_bytes": ru,
             "host_batch_bytes": batch_bytes,
+            "batch_gather": gather,
             "lr": lr,
         }
         return rec, commit, valid
@@ -1417,7 +1452,6 @@ class FLServer:
         cohort's broadcast version, and enqueue one arrival event per
         admitted client at ``clock + latency``. Returns the number of
         events enqueued (0 = nothing admitted / everyone crashed)."""
-        from repro.data.loader import client_step_count
         from repro.fl import async_engine as async_lib
         from repro.fl.stream_engine import chunk_layout, from_chunks, to_chunks
 
@@ -1481,14 +1515,9 @@ class FLServer:
             stacked_state = tree_stack(states) if states and states[0] else {}
             stacked_res = tree_stack(residents) if residents else None
 
-        S = max(client_step_count(len(self.partitions[c]), self.ccfg.batch,
-                                  self.ccfg.epochs) for c in cids)
-        batches, step_mask = stack_client_epochs(
-            self.data, self.partitions, cids, self.ccfg.batch,
-            self.ccfg.epochs, [int(s) for s in seeds],
-            pad_steps=max(S, 1), pad_clients=pad)
-        batches_xs = to_chunks(jax.tree.map(jnp.asarray, batches),
-                               n_chunks, chunk)
+        put, step_mask, _, _ = self._build_batches(cids, seeds,
+                                                   pad_clients=pad)
+        batches_xs = to_chunks(put(), n_chunks, chunk)
         eff_pad = np.zeros(C + pad, np.float32)
         eff_pad[:C] = eff
         sizes = np.asarray([len(self.partitions[c]) for c in cids],

@@ -7,7 +7,9 @@ for every strategy × personalization mode × codec (error feedback
 threaded through the stacked rows), with identical wire bytes. The
 streamed data path (``ChunkBatchSource``) must materialize bit-identical
 batches to the eager full-cohort stack, and the pre-sized pad slots must
-equal what the old concatenate path produced. Shared harness:
+equal what the old concatenate path produced. The stack gathered on the
+device from the resident dataset (``DeviceDataset``) must equal the host
+stack bit for bit, and whole rounds on either must agree. Shared harness:
 ``tests/parity.py``.
 """
 import jax
@@ -29,6 +31,8 @@ from parity import (
 from repro.data import (
     ChunkBatchSource,
     VirtualPartitions,
+    dirichlet_partition,
+    loader,
     stack_client_epochs,
 )
 
@@ -169,6 +173,78 @@ def test_stack_pad_clients_presized(task):
         assert not padded[k][3:].any()
     np.testing.assert_array_equal(mask, pmask[:3])
     assert not pmask[3:].any()
+
+
+def _ragged_parts(tr):
+    """A dirichlet partition with one client smaller than a batch of 16
+    (client 1) and one empty client (client 2)."""
+    parts = dirichlet_partition(tr["y"], N_CLIENTS, 0.5, seed=1)
+    parts[1], parts[2] = parts[1][:5], parts[2][:0]
+    return parts
+
+
+@pytest.mark.parametrize("extra_steps,pad_clients", [(None, 0), (3, 3)],
+                         ids=["tiny-and-empty", "pad-steps-and-clients"])
+def test_device_gather_matches_host_stack(task, extra_steps, pad_clients):
+    """The device gather of ``epoch_indices`` == ``stack_client_epochs``,
+    bit for bit and dtype for dtype: tiny-client wrap, empty client,
+    repeated pad steps and pad client rows included, for samples of one
+    axis and of several (kept flat on the device)."""
+    tr = task["tr"]
+    parts = _ragged_parts(tr)
+    tr = {**tr, "img": tr["x"].reshape(len(tr["x"]), 4, 8, 8)}
+    cids = [0, 1, 2, 3, 5]
+    seeds = [11, 22, 33, 2 ** 40 + 7, 55]
+    real_steps = max(len(parts[c]) // 16 for c in cids) * 2
+    pad_steps = None if extra_steps is None else real_steps + extra_steps
+    host, mask = stack_client_epochs(tr, parts, cids, 16, 2, seeds,
+                                     pad_steps=pad_steps,
+                                     pad_clients=pad_clients)
+    ids, zero_rows, dmask = loader.epoch_indices(
+        parts, cids, 16, 2, seeds, pad_steps, pad_clients)
+    assert mask.shape[1] == (pad_steps or real_steps)
+    assert mask.sum(1).max() == real_steps
+    np.testing.assert_array_equal(dmask, mask)
+    np.testing.assert_array_equal(
+        zero_rows, [False, False, True, False, False] + [True] * pad_clients)
+    dev = loader.DeviceDataset(tr).gather(ids, zero_rows)
+    assert set(dev) == set(host)
+    for k in host:
+        assert dev[k].dtype == host[k].dtype
+        assert np.asarray(dev[k]).tobytes() == host[k].tobytes()
+    assert not host["x"][2].any() and not host["x"][len(cids):].any()
+
+
+@pytest.mark.parametrize("engine,kw,other", [
+    ("batched", {}, "host"),
+    ("streaming", dict(chunk=3), "host"),
+    ("streaming", dict(chunk=3), "chunked"),
+    ("async", dict(chunk=3), "host"),
+])
+def test_round_history_device_gather_equals_host(task, monkeypatch, engine,
+                                                 kw, other):
+    """Whole rounds on the device-gathered stack == rounds on the host
+    stack (the budget helper forced to refuse the device copy) or, for
+    the streaming engine, on the chunked stream: same losses, bytes,
+    arrivals and global model, bit for bit."""
+    ragged = {**task, "parts": _ragged_parts(task["tr"])}
+    ref = run_server(ragged, engine, rounds=3, participation=0.75, **kw)
+    if other == "chunked":
+        got = run_server(ragged, engine, rounds=3, participation=0.75,
+                         data_stream="chunked", **kw)
+    else:
+        monkeypatch.setattr(loader, "device_data_budget", lambda device: 0)
+        got = run_server(ragged, engine, rounds=3, participation=0.75,
+                         **kw)
+    assert ref._device_data and not got._device_data
+    for r, g in zip(ref.history, got.history, strict=True):
+        for key in ("sampled", "arrived_mask", "mean_loss", "down_bytes",
+                    "up_bytes", "comm_gb"):
+            assert r.get(key) == g.get(key), key
+        if engine != "async":
+            assert (r["batch_gather"], g["batch_gather"]) == ("device",
+                                                              "host")
+    assert maxdiff(ref.global_params, got.global_params) == 0.0
 
 
 def test_virtual_partitions_deterministic():

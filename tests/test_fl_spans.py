@@ -6,9 +6,11 @@ trace as the device's operations. Each case runs two rounds of the
 shared tiny FedAvg task under ``jax.profiler.trace`` and reads the
 ``.xplane.pb`` back: every span the engine records sits on the calling
 thread's line, inside its round's ``fl.round``, in the order the round
-runs its phases. ``host_batch_bytes`` is the byte size of the round's
-batch stack, whether the stack is built eagerly or fetched a chunk at
-a time.
+runs its phases. ``host_batch_bytes`` counts what the host builds for
+the round's batches: the int32 index array where the stack is gathered
+on the device (``batch_gather`` "device", the eager path here), the
+byte size of the stack where the host builds it (``"host"``: the
+chunked stream).
 """
 import glob
 import os
@@ -18,7 +20,7 @@ import numpy as np
 import pytest
 
 from parity import get_task, run_server
-from repro.data.loader import stack_client_epochs
+from repro.data.loader import epoch_indices, stack_client_epochs
 
 PHASES = ("fl.round.select", "fl.round.arena_gather",
           "fl.round.stack_batches", "fl.round.put_batches",
@@ -80,10 +82,21 @@ def _stack_nbytes(task, cids, batch, pad):
     return sum(int(b.nbytes) for b in batches.values())
 
 
+def _index_nbytes(task, cids, batch, pad):
+    ids, zero_rows, step_mask = epoch_indices(
+        task["parts"], cids, batch, 1, [0] * len(cids), pad_clients=pad)
+    assert ids.dtype == np.int32
+    assert ids.shape == step_mask.shape + (batch,)   # (C + pad, S, B)
+    return int(ids.nbytes)
+
+
 def test_batched_round_counts_its_batch_stack(task):
     srv = run_server(task, "batched", rounds=2)
     for rec in srv.history:
-        assert rec["host_batch_bytes"] == _stack_nbytes(
+        assert rec["batch_gather"] == "device"
+        assert rec["host_batch_bytes"] == _index_nbytes(
+            task, rec["sampled"], 16, pad=0)
+        assert rec["host_batch_bytes"] < _stack_nbytes(
             task, rec["sampled"], 16, pad=0)
 
 
@@ -94,10 +107,12 @@ def test_streaming_round_counts_its_batch_stack_eager_or_chunked(task):
                          data_stream="chunked")
     for a, b in zip(eager.history, chunked.history):
         assert a["sampled"] == b["sampled"]
-        assert a["host_batch_bytes"] == b["host_batch_bytes"]
         pad = a["chunks"] * a["client_chunk"] - len(a["sampled"])
         assert pad == 2
-        assert a["host_batch_bytes"] == _stack_nbytes(task, a["sampled"],
+        assert (a["batch_gather"], b["batch_gather"]) == ("device", "host")
+        assert a["host_batch_bytes"] == _index_nbytes(task, a["sampled"],
                                                       16, pad)
-        assert a["host_batch_bytes"] > 0
+        assert b["host_batch_bytes"] == _stack_nbytes(task, b["sampled"],
+                                                      16, pad)
+        assert 0 < a["host_batch_bytes"] < b["host_batch_bytes"]
     assert np.isfinite(eager.history[-1]["mean_loss"])
