@@ -18,6 +18,7 @@ from repro.configs import (  # noqa: E402,F401
     chameleon_34b,
     chatglm3_6b,
     gemma3_12b,
+    granite_4_0_h_micro,
     llama3_405b,
     llama4_scout_17b_a16e,
     mixtral_8x22b,
@@ -38,6 +39,7 @@ ASSIGNED = [
     "zamba2-2.7b",
     "whisper-small",
     "xlstm-125m",
+    "granite-4.0-h-micro",
 ]
 
 __all__ = [

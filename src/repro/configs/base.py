@@ -53,7 +53,8 @@ class ArchConfig:
     local_global_period: int = 0   # gemma3: every Nth layer is global
     local_window: int = 0          # window used by the local layers
     qk_norm: bool = False
-    rope_style: str = "full"       # full | half (chatglm 2d-RoPE)
+    rope_style: str = "full"       # full | half (chatglm 2d-RoPE) | none (NoPE)
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(head_dim)
     rope_base: float = 10000.0
 
     # hybrid / ssm
@@ -61,8 +62,11 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_head_dim: int = 64
+    ssm_conv_bias: bool = False    # mamba2 depthwise conv with a bias
     attn_every: int = 0            # zamba2: shared attn+mlp block period
-    block_pattern: str = ""        # xlstm: e.g. "smmm" repeated
+    block_pattern: str = ""        # per-layer kinds, repeated over the
+                                   # stack: xlstm "smmm" (s/mLSTM); hybrid
+                                   # family "mmmmmammmm" (mamba/attention)
 
     # enc-dec (whisper)
     encoder_layers: int = 0
@@ -70,6 +74,11 @@ class ArchConfig:
 
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
+    # granite-4.0-h (muP-style): h0 = embedding_multiplier * embed,
+    # h += residual_multiplier * block(h), logits = h @ unembed / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     act: str = "silu"
 
     # compute policy
@@ -110,7 +119,11 @@ class ArchConfig:
             kw["attn_every"] = 2
             kw["n_layers"] = 4
         if self.block_pattern:
-            kw["block_pattern"] = self.block_pattern[:4] or "sm"
+            # four layers that still hold every kind of the pattern
+            kinds = [k for k in dict.fromkeys(self.block_pattern)
+                     if k not in self.block_pattern[:4]]
+            kw["block_pattern"] = (self.block_pattern[:4 - len(kinds)]
+                                   + "".join(kinds))
             kw["n_layers"] = 4
         if self.encoder_layers:
             kw["encoder_layers"] = 2

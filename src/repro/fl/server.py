@@ -1131,6 +1131,19 @@ class FLServer:
         return (functools.partial(jax.tree.map, jnp.asarray, batches),
                 step_mask, "host", data_bytes(batches))
 
+    def _train_tokens(self, step_mask, mask) -> int:
+        """Target positions the round trained on, counted on the host
+        from shapes: the real local steps (``step_mask``) of the clients
+        whose uploads arrive (``mask``), times ``batch``, times the
+        ``S`` of an ``(N, S + 1)`` ``tokens`` array; 0 for data that
+        holds no token sequences."""
+        tokens = self.data.get("tokens")
+        if tokens is None or np.ndim(tokens) != 2:
+            return 0
+        arrived = np.asarray(mask) > 0
+        steps = np.asarray(step_mask)[: len(arrived)][arrived].sum()
+        return int(steps) * self.ccfg.batch * (np.shape(tokens)[1] - 1)
+
     # ------------------------------------------------ batched engine
     def _run_round_batched(self, sampled, mask, seeds, lr, down_dec,
                            down_bytes, sel_mask=None, fault=None):
@@ -1242,6 +1255,7 @@ class FLServer:
             "up_bytes": ru,
             "host_batch_bytes": batch_bytes,
             "batch_gather": gather,
+            "train_tokens": self._train_tokens(step_mask, mask),
             "lr": lr,
         }
         return rec, commit, valid
@@ -1415,6 +1429,7 @@ class FLServer:
             "up_bytes": ru,
             "host_batch_bytes": batch_bytes,
             "batch_gather": gather,
+            "train_tokens": self._train_tokens(step_mask, mask),
             "lr": lr,
         }
         return rec, commit, valid

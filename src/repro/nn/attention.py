@@ -3,8 +3,10 @@
 Features used by the assigned archs: grouped-query attention, rotary
 embeddings (full / half "2d"), qk-norm (qwen3/gemma3/chameleon), sliding
 windows (mixtral), per-layer local/global windows (gemma3, passed as a
-traced scalar so layers can be scanned), cross-attention (whisper), and
-ring-buffer KV caches for windowed decode at 500k.
+traced scalar so layers can be scanned), cross-attention (whisper),
+NoPE (``rope_style="none"``) with a configured score scale
+(granite-4.0-h's ``attention_multiplier``), and ring-buffer KV caches
+for windowed decode at 500k.
 
 Full-sequence attention scans over query chunks (flash-style memory
 behaviour: the (C, S) score tile is the only quadratic buffer alive).
@@ -40,6 +42,11 @@ def init_attention(key: jax.Array, cfg: ArchConfig, *, cross: bool = False) -> D
     return p
 
 
+def score_scale(cfg: ArchConfig) -> float:
+    """The factor on q·k: ``cfg.attention_multiplier``, else 1/sqrt(hd)."""
+    return cfg.attention_multiplier or 1.0 / (cfg.resolved_head_dim() ** 0.5)
+
+
 def _project_qkv(p, cfg: ArchConfig, xq, xkv, positions_q, positions_kv, dtype, use_pallas):
     """Project and rope q (from xq) and k,v (from xkv)."""
     hd = cfg.resolved_head_dim()
@@ -56,7 +63,8 @@ def _project_qkv(p, cfg: ArchConfig, xq, xkv, positions_q, positions_kv, dtype, 
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if positions_q is not None:  # rope disabled for cross-attn / whisper abs
+    if positions_q is not None and cfg.rope_style != "none":
+        # rope disabled for cross-attn / whisper abs / NoPE
         q = layers.apply_rope(q, positions_q, cfg.rope_base, rotary_frac)
         k = layers.apply_rope(k, positions_kv, cfg.rope_base, rotary_frac)
     return q, k, v
@@ -116,7 +124,7 @@ def full_attention(
     if Spad != S:
         q = jnp.pad(q, ((0, 0), (0, Spad - S), (0, 0), (0, 0)))
     qc = q.reshape(B, n_chunks, C, Hkv, G, hd)
-    scale = 1.0 / (hd ** 0.5)
+    scale = score_scale(cfg)
 
     kv_pos = jnp.arange(Skv)
     if window is None:
@@ -239,7 +247,7 @@ def _chunked_attend(q, k, v, cfg, *, window, chunk):
     qc = jnp.moveaxis(q.reshape(B, n_chunks, C, Hkv, G, hd), 1, 0)
     kv_pos = jnp.arange(S)
     w = jnp.asarray(0 if window is None else window, jnp.int32)
-    scale = 1.0 / (hd ** 0.5)
+    scale = score_scale(cfg)
 
     def chunk_fn(carry, qi_idx):
         qi, idx = qi_idx
@@ -286,7 +294,7 @@ def decode_attention(
 
     qh = q.reshape(B, Hkv, G, hd)
     s = jnp.einsum("bkgh,bskh->bkgs", qh, ck).astype(jnp.float32)
-    s = s / (hd ** 0.5)
+    s = s * score_scale(cfg)
     # slot i holds global position: before wrap, i; after, the newest
     # S_cache positions — valid iff written (slot idx <= pos) and within window
     idx = jnp.arange(S_cache)
@@ -318,7 +326,7 @@ def cross_decode_attention(
     k, v = kv
     q = dense(p["wq"], x, cfg.param, dtype, use_pallas).reshape(B, Hkv, G, hd)
     s = jnp.einsum("bkgh,bskh->bkgs", q, k).astype(jnp.float32)
-    pbs = jax.nn.softmax(s / (hd ** 0.5), axis=-1)
+    pbs = jax.nn.softmax(s * score_scale(cfg), axis=-1)
     out = jnp.einsum("bkgs,bskh->bkgh", pbs.astype(v.dtype), v).reshape(B, 1, H * hd)
     return dense(p["wo"], out, cfg.param, dtype, use_pallas)
 

@@ -12,7 +12,8 @@ Mamba2 kernel: each chunk's intra-term is a masked (C×C) matmul on the
 MXU, the inter-term carries the (H, P, N) state.
 
 The in/out/gate projections are FedPara-factorized; the SSM dynamics
-parameters (A_log, D, dt bias, conv) are small and stay dense.
+parameters (A_log, D, dt bias, conv and its optional bias) are small and
+stay dense. The in-projection's columns are laid out (x, z, B, C, dt).
 """
 from __future__ import annotations
 
@@ -37,7 +38,7 @@ def init_mamba(key: jax.Array, cfg: ArchConfig) -> Dict:
     d_inner, H, P = ssm_dims(cfg)
     N = cfg.ssm_state
     ks = jax.random.split(key, 8)
-    return {
+    p = {
         "w_in": init_dense(ks[0], d, 2 * d_inner + 2 * N + H, cfg.param),  # x, z, B, C, dt
         "w_out": init_dense(ks[1], d_inner, d, cfg.param),
         "conv_w": jax.random.normal(ks[2], (cfg.ssm_conv, d_inner + 2 * N), jnp.float32) * 0.2,
@@ -46,6 +47,9 @@ def init_mamba(key: jax.Array, cfg: ArchConfig) -> Dict:
         "dt_bias": jnp.zeros((H,), jnp.float32),
         "norm": {"scale": jnp.ones((d_inner,), jnp.float32)},
     }
+    if cfg.ssm_conv_bias:
+        p["conv_b"] = jnp.zeros((d_inner + 2 * N,), jnp.float32)
+    return p
 
 
 def _split_proj(proj, cfg):
@@ -60,8 +64,9 @@ def _split_proj(proj, cfg):
     return xbc, z, B, C, dt
 
 
-def _causal_conv(x: jax.Array, w: jax.Array, state=None):
-    """Depthwise causal conv along seq. x: (B,S,D), w: (K,D).
+def _causal_conv(x: jax.Array, w: jax.Array, state=None, b=None):
+    """Depthwise causal conv along seq, then SiLU. x: (B,S,D), w: (K,D),
+    optional bias b: (D,).
 
     Returns conv output and the trailing (K-1) inputs as next state."""
     K = w.shape[0]
@@ -69,6 +74,8 @@ def _causal_conv(x: jax.Array, w: jax.Array, state=None):
         state = jnp.zeros((x.shape[0], K - 1, x.shape[-1]), x.dtype)
     xp = jnp.concatenate([state, x], axis=1)
     out = sum(xp[:, i: i + x.shape[1]] * w[i] for i in range(K))
+    if b is not None:
+        out = out + b
     new_state = xp[:, -(K - 1):] if K > 1 else state
     return jax.nn.silu(out), new_state
 
@@ -90,7 +97,7 @@ def mamba_forward(
     proj = dense(p["w_in"], x, cfg.param, dtype, use_pallas)
     xbc_raw, z, Bmat, Cmat, dt = _split_proj(proj, cfg)
     conv_in = jnp.concatenate([xbc_raw, Bmat, Cmat], axis=-1).astype(jnp.float32)
-    conv_out, _ = _causal_conv(conv_in, p["conv_w"])
+    conv_out, _ = _causal_conv(conv_in, p["conv_w"], b=p.get("conv_b"))
     final_conv_state = conv_in[:, -(cfg.ssm_conv - 1):] if cfg.ssm_conv > 1 else None
     xs = conv_out[..., :d_inner]
     Bmat = conv_out[..., d_inner: d_inner + N]
@@ -109,17 +116,16 @@ def mamba_forward(
         Bmat = jnp.pad(Bmat, ((0, 0), (0, pad), (0, 0)))
         Cmat = jnp.pad(Cmat, ((0, 0), (0, pad), (0, 0)))
         dt = dt * (jnp.arange(Sp) < S).astype(dt.dtype)[None, :, None]
-    a = jnp.exp(-dt * jnp.exp(p["A_log"]))                           # decay in (0,1]
+    loga = -dt * jnp.exp(p["A_log"])                   # log decay, a in (0,1]
 
     def reshape_c(t):  # (B,S,...) -> (nc, B, C, ...)
         return jnp.moveaxis(t.reshape(B, nc, C, *t.shape[2:]), 1, 0)
 
-    ac, dtc, xc = reshape_c(a), reshape_c(dt), reshape_c(xh)
+    ac, dtc, xc = reshape_c(loga), reshape_c(dt), reshape_c(xh)
     Bc, Cc = reshape_c(Bmat), reshape_c(Cmat)
 
     def chunk_step(state, inp):
-        a_, dt_, x_, B_, C_ = inp                                    # (B,C,H),(B,C,H),(B,C,H,P),(B,C,N)
-        loga = jnp.log(a_ + 1e-20)
+        loga, dt_, x_, B_, C_ = inp                                  # (B,C,H),(B,C,H),(B,C,H,P),(B,C,N)
         cum = jnp.cumsum(loga, axis=1)                               # (B,C,H)
         # intra-chunk: y_t += C_t · Σ_{s<=t} exp(cum_t - cum_s) dt_s x_s B_sᵀ
         rel = cum[:, :, None, :] - cum[:, None, :, :]                # (B,C,C,H) t,s
@@ -148,7 +154,7 @@ def mamba_forward(
     y = y * jax.nn.silu(z.astype(dtype))
     # group RMS norm on d_inner (mamba2 style)
     yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + 1e-6) * p["norm"]["scale"]).astype(dtype)
+    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + cfg.norm_eps) * p["norm"]["scale"]).astype(dtype)
     out = dense(p["w_out"], y, cfg.param, dtype, use_pallas)
     if return_state:
         return out, (final_state, final_conv_state)
@@ -180,7 +186,8 @@ def mamba_decode_step(
     proj = dense(p["w_in"], x, cfg.param, dtype)
     xbc_raw, z, Bmat, Cmat, dt = _split_proj(proj, cfg)
     conv_in = jnp.concatenate([xbc_raw, Bmat, Cmat], axis=-1).astype(jnp.float32)
-    conv_out, conv_state = _causal_conv(conv_in, p["conv_w"], conv_state)
+    conv_out, conv_state = _causal_conv(conv_in, p["conv_w"], conv_state,
+                                        b=p.get("conv_b"))
     xs = conv_out[..., :d_inner]
     Bm = conv_out[:, 0, d_inner: d_inner + N]                        # (B,N)
     Cm = conv_out[:, 0, d_inner + N:]
@@ -194,5 +201,5 @@ def mamba_decode_step(
     y = jnp.einsum("bhpn,bn->bhp", ssm_state, Cm) + p["D"][None, :, None] * xh
     y = y.reshape(B, 1, d_inner).astype(dtype) * jax.nn.silu(z.astype(dtype))
     yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + 1e-6) * p["norm"]["scale"]).astype(dtype)
+    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + cfg.norm_eps) * p["norm"]["scale"]).astype(dtype)
     return dense(p["w_out"], y, cfg.param, dtype), (ssm_state, conv_state)

@@ -1,5 +1,6 @@
-"""Model assembly: decoder-only LMs, MoE, hybrid (zamba2), xLSTM stacks,
-and the whisper enc-dec — all driven by ArchConfig.
+"""Model assembly: decoder-only LMs, MoE, hybrid (zamba2's shared block,
+granite-4.0-h's per-layer mixer pattern), xLSTM stacks, and the whisper
+enc-dec — all driven by ArchConfig.
 
 Layer iteration supports two modes:
   scan=True   lax.scan over stacked layer params (compact HLO, fast
@@ -10,6 +11,7 @@ Layer iteration supports two modes:
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -152,9 +154,11 @@ def mlp(p, x, cfg: ArchConfig, dtype, use_pallas=False):
 # ----------------------------------------------------------- loss utilities
 
 def chunked_ce_loss(h: jax.Array, unembed_w: jax.Array, targets: jax.Array,
-                    mask: jax.Array, chunk: int) -> jax.Array:
+                    mask: jax.Array, chunk: int,
+                    logit_scale: float = 1.0) -> jax.Array:
     """Next-token CE, unembedding seq-chunk by seq-chunk (bounds the fp32
-    logit buffer to (B, chunk, V))."""
+    logit buffer to (B, chunk, V)). The fp32 logits are multiplied by
+    ``logit_scale`` before the softmax."""
     B, S, d = h.shape
     C = min(chunk, S)
     nc = (S + C - 1) // C
@@ -174,6 +178,8 @@ def chunked_ce_loss(h: jax.Array, unembed_w: jax.Array, targets: jax.Array,
         # across the vocab-sharded axis would force GSPMD to all-gather
         # the full fp32 logits.
         logits = jnp.einsum("bcd,dv->bcv", hi, unembed_w).astype(jnp.float32)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
         logits = constrain(logits, "batch", None, "vocab")
         lse = jax.nn.logsumexp(logits, axis=-1)
         onehot = jax.nn.one_hot(ti, logits.shape[-1], dtype=logits.dtype)
@@ -558,6 +564,209 @@ class HybridSSM:
                                int8=int8)
 
 
+# ====================================================== per-layer hybrid
+
+class HybridLM:
+    """Hybrid stack with a per-layer mixer pattern (granite-4.0-h).
+
+    ``cfg.block_pattern`` repeats over the ``n_layers``: ``m`` is a
+    Mamba-2 mixer, ``a`` a GQA attention mixer. Every layer, of either
+    kind, has its own weights and its own MLP::
+
+        h += residual_multiplier * mixer(rms(h))
+        h += residual_multiplier * mlp(rms(h))
+
+    after ``h = embedding_multiplier * embed[tokens]``; the logits are
+    divided by ``logits_scaling``. Each kind's layers are stacked
+    ``(periods, layers of that kind in a period, ...)``. Training scans
+    over whole periods and, inside one, over each run of same-kind
+    layers (``iterate_layers``, with DecoderLM's remat and
+    ``use_pallas``). Mixers, MLPs and the unembedding run under the
+    named scopes ``lm.mamba``, ``lm.attention``, ``lm.mlp`` and
+    ``lm.unembed``."""
+
+    KINDS = {"m": "mamba", "a": "attn"}
+
+    def __init__(self, cfg: ArchConfig, opts: ModelOptions = ModelOptions()):
+        pat = cfg.block_pattern
+        if set(pat) - set(self.KINDS) or cfg.n_layers % len(pat):
+            raise ValueError(f"pattern {pat!r} over {cfg.n_layers} layers")
+        self.cfg, self.opts = cfg, opts
+        self.n_periods = cfg.n_layers // len(pat)
+        self.per_period = {k: pat.count(k) for k in self.KINDS if k in pat}
+        # [(kind, first index among that kind's layers, run length)]
+        self.runs, seen = [], dict.fromkeys(self.KINDS, 0)
+        for kind, run in itertools.groupby(pat):
+            n = len(list(run))
+            self.runs.append((kind, seen[kind], n))
+            seen[kind] += n
+
+    # ---------------- init
+    def _init_layer(self, kind: str, key) -> Dict:
+        cfg = self.cfg
+        ks = jax.random.split(key, 2)
+        mixer = (ssm_mod.init_mamba(ks[0], cfg) if kind == "m"
+                 else attn.init_attention(ks[0], cfg))
+        return {"ln1": init_scale(cfg.d_model), "mixer": mixer,
+                "ln2": init_scale(cfg.d_model), "mlp": init_mlp(ks[1], cfg)}
+
+    def init_params(self, key) -> Dict:
+        cfg = self.cfg
+        ks = jax.random.split(key, 2 + len(self.per_period))
+        emb = jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model), jnp.float32)
+        p = {"embed": {"w": emb * (1.0 / cfg.d_model ** 0.5)},
+             "final_norm": init_scale(cfg.d_model)}
+        for k, (kind, n) in zip(ks[2:], self.per_period.items()):
+            layers = jax.vmap(lambda kk, kind=kind: self._init_layer(kind, kk))(
+                jax.random.split(k, self.n_periods * n))
+            p[self.KINDS[kind]] = jax.tree.map(
+                lambda a, n=n: a.reshape(self.n_periods, n, *a.shape[1:]), layers)
+        if not cfg.tie_embeddings:
+            unemb = jax.random.normal(ks[1], (cfg.d_model, cfg.vocab_size), jnp.float32)
+            p["unembed"] = {"w": unemb * (1.0 / cfg.d_model ** 0.5)}
+        return p
+
+    # ---------------- one layer
+    def _mixer(self, kind: str, p, x, mode="train", cache=None, pos=None):
+        """The layer's mixer on normed ``x``; returns (y, new cache)."""
+        cfg, opts = self.cfg, self.opts
+        if kind == "m":
+            with jax.named_scope("lm.mamba"):
+                if mode == "train":
+                    return ssm_mod.mamba_forward(
+                        p, x, cfg, chunk=opts.ssm_chunk, dtype=opts.dtype,
+                        use_pallas=opts.use_pallas), None
+                if mode == "prefill":
+                    y, (ssm_s, conv_s) = ssm_mod.mamba_forward(
+                        p, x, cfg, chunk=opts.ssm_chunk, dtype=opts.dtype,
+                        use_pallas=opts.use_pallas, return_state=True)
+                else:
+                    y, (ssm_s, conv_s) = ssm_mod.mamba_decode_step(
+                        p, x, cfg, (cache["ssm"], cache["conv"]),
+                        dtype=opts.dtype)
+                return y, {"ssm": ssm_s, "conv": conv_s}
+        with jax.named_scope("lm.attention"):
+            if mode == "train":
+                return attn.full_attention(
+                    p, x, cfg, window=0, chunk=opts.attn_chunk,
+                    dtype=opts.dtype, use_pallas=opts.use_pallas), None
+            if mode == "prefill":
+                y, (k, v) = attn.prefill_attention(
+                    p, x, cfg, (cache["k"], cache["v"]), window=0,
+                    chunk=opts.attn_chunk, dtype=opts.dtype,
+                    use_pallas=opts.use_pallas)
+            else:
+                y, (k, v) = attn.decode_attention(
+                    p, x, cfg, (cache["k"], cache["v"]), pos, window=0,
+                    dtype=opts.dtype, use_pallas=opts.use_pallas)
+            return y, {"k": k, "v": v}
+
+    def _layer(self, kind: str, p, h, mode="train", cache=None, pos=None):
+        cfg, opts = self.cfg, self.opts
+        rm = cfg.residual_multiplier
+        y, cache = self._mixer(kind, p["mixer"], rms_norm(h, p["ln1"], cfg.norm_eps),
+                               mode, cache, pos)
+        h = h + rm * y
+        with jax.named_scope("lm.mlp"):
+            h = h + rm * mlp(p["mlp"], rms_norm(h, p["ln2"], cfg.norm_eps),
+                             cfg, opts.dtype, opts.use_pallas)
+        return constrain(h, "batch", "seq", None), cache
+
+    def _embed(self, params, tokens):
+        w = params["embed"]["w"][tokens]
+        return (w * self.cfg.embedding_multiplier).astype(self.opts.dtype)
+
+    def _unembed_w(self, params, dtype):
+        if self.cfg.tie_embeddings:
+            return params["embed"]["w"].astype(dtype).T
+        return params["unembed"]["w"].astype(dtype)
+
+    # ---------------- train forward
+    def hidden_states(self, params, tokens) -> jax.Array:
+        cfg, opts = self.cfg, self.opts
+        h = constrain(self._embed(params, tokens), "batch", "seq", None)
+        bodies = {k: (lambda c, p, _, k=k: self._layer(k, p, c)[0])
+                  for k in self.per_period}
+
+        def period(h, pp):
+            for kind, start, n in self.runs:
+                run = jax.tree.map(lambda a: a[start: start + n],
+                                   pp[self.KINDS[kind]])
+                h = iterate_layers(bodies[kind], h, run, jnp.zeros((n,)), n,
+                                   opts.scan_layers, opts.remat)
+            return h
+
+        stacks = {self.KINDS[k]: params[self.KINDS[k]] for k in self.per_period}
+        if opts.scan_layers:
+            h, _ = jax.lax.scan(lambda c, pp: (period(c, pp), None), h, stacks)
+        else:
+            for i in range(self.n_periods):
+                h = period(h, _tree_index(stacks, i))
+        return rms_norm(h, params["final_norm"], cfg.norm_eps)
+
+    def loss(self, params, batch) -> jax.Array:
+        tokens = batch["tokens"]
+        h = self.hidden_states(params, tokens[:, :-1])
+        targets = tokens[:, 1:]
+        mask = jnp.ones_like(targets, jnp.float32)
+        with jax.named_scope("lm.unembed"):
+            return chunked_ce_loss(h, self._unembed_w(params, self.opts.dtype),
+                                   targets, mask, self.opts.logit_chunk,
+                                   1.0 / self.cfg.logits_scaling)
+
+    # ---------------- serving (layers unrolled, as HybridSSM)
+    def _layer_sites(self):
+        """[(kind, period, index among the period's layers of that kind)]
+        of every layer in stack order."""
+        out = []
+        for t in range(self.n_periods):
+            for kind, start, n in self.runs:
+                out.extend((kind, t, start + j) for j in range(n))
+        return out
+
+    def init_cache(self, batch: int, max_seq: int) -> Dict:
+        cfg, out = self.cfg, {}
+        for kind, n in self.per_period.items():
+            sites = self.n_periods * n
+            c = (ssm_mod.init_mamba_cache(cfg, batch, sites) if kind == "m"
+                 else attn.init_kv_cache(cfg, batch, max_seq, sites,
+                                         dtype=self.opts.dtype))
+            out[self.KINDS[kind]] = jax.tree.map(
+                lambda a, n=n: a.reshape(self.n_periods, n, *a.shape[1:]), c)
+        return out
+
+    def _serve(self, params, h, cache, mode, pos=None):
+        new = {name: [] for name in cache}
+        for kind, t, i in self._layer_sites():
+            name = self.KINDS[kind]
+            p = _tree_index(_tree_index(params[name], t), i)
+            c = _tree_index(_tree_index(cache[name], t), i)
+            h, c = self._layer(kind, p, h, mode, c, pos)
+            new[name].append(c)
+        cache = {}
+        for name, cs in new.items():
+            n = len(cs) // self.n_periods
+            cache[name] = jax.tree.map(
+                lambda *xs, n=n: jnp.stack(xs).reshape(
+                    self.n_periods, n, *xs[0].shape), *cs)
+        h = rms_norm(h, params["final_norm"], self.cfg.norm_eps)
+        logits = jnp.einsum("bd,dv->bv", h[:, -1].astype(jnp.float32),
+                            self._unembed_w(params, jnp.float32))
+        return cache, logits / self.cfg.logits_scaling
+
+    def prefill(self, params, tokens, cache) -> Tuple[Dict, jax.Array]:
+        return self._serve(params, self._embed(params, tokens), cache, "prefill")
+
+    def decode_step(self, params, cache, token, pos) -> Tuple[jax.Array, Dict]:
+        cache, logits = self._serve(params, self._embed(params, token), cache,
+                                    "decode", pos)
+        return logits, cache
+
+    def precompose(self, params, int8: bool = False):
+        return precompose_tree(params, self.cfg.param, self.opts.dtype,
+                               int8=int8)
+
+
 # ================================================================ xLSTM stack
 
 class XLSTMStack:
@@ -891,6 +1100,8 @@ def build_model(cfg: ArchConfig, opts: ModelOptions = ModelOptions()):
         return EncDecLM(cfg, opts)
     if cfg.attn_every:
         return HybridSSM(cfg, opts)
+    if cfg.block_pattern and cfg.family == "hybrid":
+        return HybridLM(cfg, opts)
     if cfg.block_pattern:
         return XLSTMStack(cfg, opts)
     return DecoderLM(cfg, opts)

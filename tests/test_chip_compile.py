@@ -9,7 +9,9 @@ compiled path, not interpret mode, is what passed.
 
 Shapes: the Shakespeare LSTM's gate factors at ``LSTMConfig()`` widths
 (8x1024 and 256x1024 at the ranks its gamma gives) with the FL client
-batch, a (4096, 14336, r=64) bf16 FFN layer, the int8 upload fold at a
+batch, a (4096, 14336, r=64) bf16 FFN layer, granite-4.0-h-micro's
+projections (Mamba-2 in/out, MLP gate and down, attention k) at 4096
+bf16 rows, vmapped over a chunk of 2 clients, the int8 upload fold at a
 16-client chunk and at one client, and the serve kernels. On a v5e:2x2
 mesh: the LSTM's held-out loss and its gradient on a global model
 replicated over the chips, and the int8 fold of client-sharded wires
@@ -46,6 +48,13 @@ def _lstm_gate_shapes():
 LSTM_SHAPES = [(CLIENT_BATCH, m, n, r, "float32")
                for m, n, r in _lstm_gate_shapes()]
 FFN_SHAPE = (128, 4096, 14336, 64, "bfloat16")
+# (rows, m, n, r) of granite-4.0-h-micro's projections at gamma 0.1: one
+# client's 4096-token document
+GRANITE_SHAPES = [(4096, 2048, 8512, 124, "bfloat16"),    # Mamba-2 in_proj
+                  (4096, 4096, 2048, 110, "bfloat16"),    # Mamba-2 out_proj
+                  (4096, 2048, 8192, 123, "bfloat16"),    # MLP gate / up
+                  (4096, 8192, 2048, 123, "bfloat16"),    # MLP down
+                  (4096, 2048, 512, 41, "bfloat16")]      # attention k / v
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +135,16 @@ def test_client_vmapped_vjp_compiles(one_chip, shape):
     text = _compiled_text(jax.vmap(_GRAD),
                           *_factor_args(one_chip, *shape, lead=(16,)))
     assert text.count("tpu_custom_call") >= 4
+
+
+@pytest.mark.parametrize("shape", GRANITE_SHAPES, ids=str)
+def test_granite_projection_forward_and_vjp_compile(one_chip, shape):
+    # a chunk of 2 clients, as the granite cell's round program runs it
+    args = _factor_args(one_chip, *shape, lead=(2,))
+    fwd = _compiled_text(
+        jax.vmap(lambda *a: ops.fedpara_matmul(*a, interpret=False)), *args)
+    assert fwd.count("tpu_custom_call") >= 1
+    assert _compiled_text(jax.vmap(_GRAD), *args).count("tpu_custom_call") >= 4
 
 
 @pytest.mark.parametrize("clients", [16, 1])

@@ -10,12 +10,14 @@ runs its phases. ``host_batch_bytes`` counts what the host builds for
 the round's batches: the int32 index array where the stack is gathered
 on the device (``batch_gather`` "device", the eager path here), the
 byte size of the stack where the host builds it (``"host"``: the
-chunked stream).
+chunked stream). ``train_tokens`` counts the target positions of the
+real local steps of the clients whose uploads arrive.
 """
 import glob
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -116,3 +118,36 @@ def test_streaming_round_counts_its_batch_stack_eager_or_chunked(task):
                                                       16, pad)
         assert 0 < a["host_batch_bytes"] < b["host_batch_bytes"]
     assert np.isfinite(eager.history[-1]["mean_loss"])
+
+
+@pytest.mark.parametrize("engine,server_kw", [
+    ("batched", {}),
+    ("streaming", dict(client_chunk=3, state_store="arena")),
+    ("streaming", dict(client_chunk=3, data_stream="chunked")),
+])
+def test_rounds_count_their_trained_tokens(engine, server_kw):
+    """Documents of 12 + 1 tokens over clients of 5 to 40 documents: a
+    client's real steps are ``size // batch`` a local epoch, the pad slots
+    and a padded step's positions are not counted."""
+    from repro.fl import ClientConfig, FLServer, ServerConfig, make_strategy
+
+    sizes = [5, 9, 16, 23, 40, 7]
+    seq, batch, epochs = 12, 4, 2
+    tokens = np.random.RandomState(0).randint(0, 50, (sum(sizes), seq + 1))
+    bounds = np.cumsum([0] + sizes)
+    parts = [np.arange(a, b) for a, b in zip(bounds[:-1], bounds[1:])]
+
+    def loss_fn(p, b):
+        emb = p["w"][b["tokens"][:, :-1]]
+        return jnp.mean((emb.sum(-1) - b["tokens"][:, 1:]) ** 2)
+
+    srv = FLServer(loss_fn, {"w": jnp.ones((50, 8)) * 0.1},
+                   {"tokens": tokens.astype(np.int32)}, parts,
+                   make_strategy("fedavg"),
+                   ClientConfig(lr=0.01, batch=batch, epochs=epochs),
+                   ServerConfig(clients=len(sizes), participation=4 / 6,
+                                rounds=2, engine=engine, **server_kw))
+    srv.run()
+    for rec in srv.history:
+        steps = sum(sizes[c] // batch * epochs for c in rec["sampled"])
+        assert rec["train_tokens"] == steps * batch * seq
